@@ -13,7 +13,7 @@ tests on small batches).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, Iterator, Tuple
+from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
@@ -128,7 +128,7 @@ def batch_plan(batch: int, seq: int) -> DecodePlan:
 
 
 def decode_batch(
-    wire: bytes, batch: int, seq: int, interpret: bool = True
+    wire: bytes, batch: int, seq: int, interpret: Optional[bool] = None
 ) -> Dict[str, jnp.ndarray]:
     plan = batch_plan(batch, seq)
     w32 = wire_to_u32(wire)
@@ -182,7 +182,7 @@ class HGumBatchPipeline:
     batch: int
     seq: int
     seed: int = 0
-    interpret: bool = True
+    interpret: Optional[bool] = None  # None: interpret only on the CPU
     use_kernel: bool = True
 
     def __post_init__(self):

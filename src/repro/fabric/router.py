@@ -99,7 +99,6 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .frames import (
@@ -485,12 +484,12 @@ class Router:
         local = self._build_local(T, axis_steps, q_cap, rx_cap)
         spec = P(self.axis_names)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(spec, spec),
                 out_specs=(spec,) * 7,
-                check_rep=False,
+                check_vma=False,
             )
         )
 
@@ -1021,11 +1020,11 @@ class Router:
 
         spec = P(self.axis_names)
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(spec,) * (8 if faulted else 5),
                 out_specs=(spec,) * 8,
-                check_rep=False,
+                check_vma=False,
             )
         )

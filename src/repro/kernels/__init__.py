@@ -14,15 +14,15 @@ from .ops import (
     encode_chunks_batch,
     encode_frames_batch,
     encode_run,
+    resolve_interpret,
     runs_from_plan,
     wire_to_u32,
     wires_to_u32,
-    write_headers,
 )
 
 __all__ = [
     "batched_runs_from_plan", "decode_batch_kernel", "decode_frames_batch",
     "decode_gather", "decode_message_kernel", "decode_run",
     "encode_chunks_batch", "encode_frames_batch", "encode_run",
-    "runs_from_plan", "wire_to_u32", "wires_to_u32", "write_headers",
+    "resolve_interpret", "runs_from_plan", "wire_to_u32", "wires_to_u32",
 ]

@@ -1,30 +1,23 @@
 """Pallas TPU kernel: HGum SER payload pass (token lanes -> phit stream).
 
 Mirror of ``phit_unpack``: pack a run of fixed-width tokens contiguously
-into the wire, and stamp HW-to-HW frame headers (paper §IV-C) onto a framed
-stream.  The aligned path is a pure reshape (one VMEM tile per grid step);
-the general path writes one token per fori_loop iteration with dynamic
-slices (store-side shift-combine would race across rows at word granularity,
-so unaligned tokens serialize within the block — documented cost model:
-aligned = vector rate, unaligned = token rate, matching the paper's "few
-extra cycles" overhead class).
+into the wire (aligned pitch only — one ``(BLOCK, stride/4)`` word tile per
+grid step, flattened to the wire outside the kernel), and assemble framed
+streams, stream-fragment rows and the RX header/payload split in batched
+row-block passes.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..fabric.frames import HDR_WORDS
-from .phit_unpack import BLOCK, _lane_mask
+from .phit_unpack import _block_rows, _lane_mask, _round_up
 
 
-def _pack_kernel_aligned(tok_ref, out_ref, *, stride_w: int):
-    # tokens arrive pre-padded to the element pitch; packing is a reshape
-    # (one VMEM tile in, one contiguous wire tile out).
-    out_ref[...] = tok_ref[...].reshape(BLOCK * stride_w)
+def _copy_kernel(tok_ref, out_ref):
+    out_ref[...] = tok_ref[...]
 
 
 def pack_run(
@@ -32,51 +25,41 @@ def pack_run(
     stride: int,  # element pitch in bytes (>= nbytes)
     nbytes: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Pack N tokens at pitch `stride` from byte 0; returns u32 wire run.
 
     Aligned fast path only (stride % 4 == 0); ragged/unaligned encoding goes
-    through the jnp oracle (`ref.encode_run_ref`) — see module docstring.
+    through the jnp oracle (`ref.encode_run_ref`).  Tokens are masked and
+    padded to the element pitch first, so the kernel writes whole
+    ``(BLOCK, stride/4)`` word tiles and the wire is their row-major view.
     """
     if stride % 4 != 0:
         raise ValueError("pack_run: stride must be 4-byte aligned (use ref path)")
     n, nlanes = tokens.shape
     assert nlanes == (nbytes + 3) // 4
-    cap = -(-n // BLOCK) * BLOCK
+    blk = _block_rows(n)
+    cap = _round_up(n, blk)
     stride_w = stride // 4
     toks = jnp.pad(
-        tokens & _lane_mask(nbytes, nlanes)[None, :],
+        tokens & _lane_mask(nbytes, nlanes),
         ((0, cap - n), (0, stride_w - nlanes)),
     )
     out = pl.pallas_call(
-        functools.partial(_pack_kernel_aligned, stride_w=stride_w),
-        grid=(cap // BLOCK,),
-        in_specs=[pl.BlockSpec((BLOCK, stride_w), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((BLOCK * stride_w,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((cap * stride_w,), jnp.uint32),
+        _copy_kernel,
+        grid=(cap // blk,),
+        in_specs=[pl.BlockSpec((blk, stride_w), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((blk, stride_w), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((cap, stride_w), jnp.uint32),
         interpret=interpret,
+        name="hgum_pack_run",
     )(toks)
-    return out[: n * stride_w]
+    return out.reshape(cap * stride_w)[: n * stride_w]
 
 
 # ---------------------------------------------------------------------------
-# frame header stamping (HW-to-HW framing, §IV-C)
+# framed streams and stream fragments (HW-to-HW framing, §IV-C)
 # ---------------------------------------------------------------------------
-
-
-def _header_kernel(wire_ref, hdr_ref, out_ref, *, n_headers: int):
-    out_ref[...] = wire_ref[...]
-
-    def body(i, _):
-        word = hdr_ref[i, 0]  # phit-word index of this header
-        size = hdr_ref[i, 1].astype(jnp.uint32)
-        level = hdr_ref[i, 2].astype(jnp.uint32)
-        pl.store(out_ref, (pl.ds(word, 1),), size[None])
-        pl.store(out_ref, (pl.ds(word + 1, 1),), level[None])
-        return 0
-
-    jax.lax.fori_loop(0, n_headers, body, 0)
 
 
 def _assemble_kernel(hdr_ref, pay_ref, out_ref):
@@ -89,7 +72,7 @@ def pack_frames_batch(
     headers: jnp.ndarray,  # (B, F, HDR_WORDS) u32 — incl. crc + route words
     payloads: jnp.ndarray,  # (B, F, frame_words) u32 — pre-masked
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Assemble B framed streams (multi-destination send) in one call.
 
@@ -112,6 +95,7 @@ def pack_frames_batch(
         out_specs=pl.BlockSpec((1, F, width), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, F, width), jnp.uint32),
         interpret=interpret,
+        name="hgum_pack_frames",
     )(headers.astype(jnp.uint32), payloads.astype(jnp.uint32))
 
 
@@ -128,7 +112,7 @@ def pack_chunks_batch(
     counts: jnp.ndarray,  # (B, 1) u32 — true element count per chunk
     *,
     block: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jnp.ndarray:
     """Assemble B small stream fragments into wire rows in one call.
 
@@ -161,6 +145,7 @@ def pack_chunks_batch(
         out_specs=pl.BlockSpec((block, width), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((capB, width), jnp.uint32),
         interpret=interpret,
+        name="hgum_pack_chunks",
     )(meta, tokens, counts)
     return out[:B]
 
@@ -175,7 +160,7 @@ def unpack_frames_batch(
     frames: jnp.ndarray,  # (N, HDR_WORDS + frame_words) u32
     *,
     block: int = 8,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple:
     """Split a batch of received frames into (headers, payloads).
 
@@ -200,26 +185,6 @@ def unpack_frames_batch(
             jax.ShapeDtypeStruct((cap, frame_words), jnp.uint32),
         ),
         interpret=interpret,
+        name="hgum_unpack_frames",
     )(fr)
     return hdr[:N], pay[:N]
-
-
-def stamp_headers(
-    wire_u32: jnp.ndarray,  # (W,) framed stream with header slots zeroed
-    headers: jnp.ndarray,  # (H, 3) int32 [word_index, size, list_level]
-    *,
-    interpret: bool = True,
-) -> jnp.ndarray:
-    """Write (size, ListLevel) frame headers into their phit slots."""
-    H = headers.shape[0]
-    return pl.pallas_call(
-        functools.partial(_header_kernel, n_headers=H),
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec(wire_u32.shape, lambda i: (0,)),
-            pl.BlockSpec((H, 3), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec(wire_u32.shape, lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct(wire_u32.shape, jnp.uint32),
-        interpret=interpret,
-    )(wire_u32, headers.astype(jnp.int32))

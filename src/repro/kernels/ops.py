@@ -2,9 +2,12 @@
 
 ``decode_runs`` is the production DES payload pass: it takes the wire plus a
 *run table* (the structure pass output — one row per uniform run of a leaf
-field) and returns the unpacked token lanes for each requested leaf.  The
-interpret flag defaults to True because this container executes TPU kernels
-on CPU; on real TPU pass interpret=False.
+field) and returns the unpacked token lanes for each requested leaf.
+
+Every wrapper takes ``interpret=None``, which :func:`resolve_interpret`
+turns into the Pallas interpreter on the CPU backend and a compiled Mosaic
+kernel on any other: on a TPU a kernel compiles or raises, and never falls
+back to the interpreter.
 """
 from __future__ import annotations
 
@@ -21,10 +24,16 @@ from .frame_pack import (
     pack_chunks_batch,
     pack_frames_batch,
     pack_run,
-    stamp_headers,
     unpack_frames_batch,
 )
 from .phit_unpack import unpack_gather, unpack_run
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` -> interpret only where the default backend is the CPU."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 def wire_to_u32(wire: bytes | np.ndarray) -> jnp.ndarray:
@@ -38,23 +47,20 @@ def wire_to_u32(wire: bytes | np.ndarray) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("base", "stride", "count", "nbytes", "interpret"))
 def decode_run(wire_u32, base: int, stride: int, count: int, nbytes: int,
-               interpret: bool = True):
-    return unpack_run(wire_u32, base, stride, count, nbytes, interpret=interpret)
+               interpret: Optional[bool] = None):
+    return unpack_run(wire_u32, base, stride, count, nbytes,
+                      interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("nbytes", "interpret"))
-def decode_gather(wire_u32, offsets, nbytes: int, interpret: bool = True):
-    return unpack_gather(wire_u32, offsets, nbytes, interpret=interpret)
+def decode_gather(wire_u32, offsets, nbytes: int, interpret: Optional[bool] = None):
+    return unpack_gather(wire_u32, offsets, nbytes,
+                         interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "nbytes", "interpret"))
-def encode_run(tokens, stride: int, nbytes: int, interpret: bool = True):
-    return pack_run(tokens, stride, nbytes, interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def write_headers(wire_u32, headers, interpret: bool = True):
-    return stamp_headers(wire_u32, headers, interpret=interpret)
+def encode_run(tokens, stride: int, nbytes: int, interpret: Optional[bool] = None):
+    return pack_run(tokens, stride, nbytes, interpret=resolve_interpret(interpret))
 
 
 @functools.partial(
@@ -67,7 +73,7 @@ def encode_frames_batch(
     routes,  # (B, 3) int32 (src, dst, seq0) per stream
     list_level: int = 1,
     frame_phits: int = 16,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     adaptive: bool = False,  # stamp the shortest-path route-word bit
 ):
     """Multi-destination SER: B wires -> B routed framed streams.
@@ -79,13 +85,14 @@ def encode_frames_batch(
         payloads_u32, nbytes, routes, list_level=list_level,
         frame_phits=frame_phits, adaptive=adaptive,
     )
-    return pack_frames_batch(hdr, data, interpret=interpret), n_frames
+    frames = pack_frames_batch(hdr, data, interpret=resolve_interpret(interpret))
+    return frames, n_frames
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_frames_batch(frames_u32, interpret: bool = True):
+def decode_frames_batch(frames_u32, interpret: Optional[bool] = None):
     """RX split of delivered frames: (N, width) -> (headers, payloads)."""
-    return unpack_frames_batch(frames_u32, interpret=interpret)
+    return unpack_frames_batch(frames_u32, interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("elem_words", "interpret"))
@@ -94,7 +101,7 @@ def encode_chunks_batch(
     tokens,  # (B, cap*elem_words) element words, zero-padded past each count
     counts,  # (B,) int32 true ELEMENT counts
     elem_words: int = 1,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Generated stream-fragment SER: B fragments -> B wire rows
     ``[meta | element words | count]`` (count after elements, §IV-B).
@@ -112,7 +119,8 @@ def encode_chunks_batch(
     nwords = counts[:, None] * jnp.uint32(elem_words)
     toks = jnp.where(col < nwords, tokens.astype(jnp.uint32), 0)
     return pack_chunks_batch(
-        jnp.asarray(meta), toks, counts[:, None], interpret=interpret
+        jnp.asarray(meta), toks, counts[:, None],
+        interpret=resolve_interpret(interpret),
     )
 
 
@@ -183,7 +191,7 @@ def decode_batch_kernel(
     row_bytes: int,
     bplan: BatchedDecodePlan,
     paths: Optional[List[str]] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Dict[str, jnp.ndarray]:
     """Batched DES payload pass on the Pallas kernels.
 
@@ -217,7 +225,7 @@ def decode_message_kernel(
     wire_u32: jnp.ndarray,
     plan: DecodePlan,
     paths: Optional[List[str]] = None,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Dict[str, jnp.ndarray]:
     """DES payload pass using the Pallas kernels (run fast-path per leaf)."""
     out = {}

@@ -2,7 +2,6 @@
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from ..core.vectorized import decode_leaf
 
@@ -44,12 +43,3 @@ def pack_run_ref(tokens: jnp.ndarray, stride: int, nbytes: int) -> jnp.ndarray:
     buf = jnp.zeros((n, stride_w), jnp.uint32)
     buf = buf.at[:, :nlanes].set(toks)
     return buf.reshape(n * stride_w)
-
-
-def stamp_headers_ref(wire_u32: jnp.ndarray, headers: np.ndarray) -> jnp.ndarray:
-    """Oracle for frame_pack.stamp_headers."""
-    w = np.asarray(wire_u32).copy()
-    for word, size, level in np.asarray(headers):
-        w[word] = np.uint32(size)
-        w[word + 1] = np.uint32(level)
-    return jnp.asarray(w)

@@ -37,7 +37,7 @@ from ..runtime import (
 )
 from ..runtime.actshard import mesh_constrainer, use_constrainer
 from .hloanalysis import HBM_BW, ICI_BW, PEAK_FLOPS, analyze
-from .mesh import make_production_mesh
+from .mesh import auto_mesh, make_production_mesh
 from .steps import (
     cache_specs,
     input_specs,
@@ -196,7 +196,7 @@ def run_cell(
 
     if mesh_shape is not None:  # hillclimb: re-factor the 256 chips
         axes = ("pod", "data", "model")[-len(mesh_shape):]
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = auto_mesh(mesh_shape, axes)
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_chips = mesh.devices.size

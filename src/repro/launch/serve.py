@@ -62,6 +62,7 @@ from ..core import (
 from ..data.schemas import request_schema, response_schema
 from ..models import init_params
 from ..runtime.scheduler import ContinuousBatcher, SchedulerConfig
+from .compile_cache import use_compile_cache
 from .steps import make_prefill_step, make_serve_step
 
 
@@ -80,24 +81,31 @@ def decode_request(wire: bytes) -> Tuple[int, List[List[int]]]:
     return msg["req_id"], [p["tokens"] for p in msg["prompts"]]
 
 
+#: the request leaves the serve plane consumes; skipping the outer
+#: 'prompts' count leaf drops one gather from the request hot path
+REQUEST_PATHS = ["req_id", "prompts.elem.tokens", "prompts.elem.tokens.elem"]
+
+
 def decode_request_batch(wires: List[bytes]) -> List[Tuple[int, List[List[int]]]]:
-    """Batched DES of N request wires: one schema walk + one gather per leaf.
+    """Batched DES of N request wires: one schema walk + one gather per leaf."""
+    bplan = batch_plans(request_schema(), wires, record_paths=REQUEST_PATHS)
+    vals = decode_batch(jnp.asarray(stack_wires(wires)), bplan)
+    return requests_from_lanes(vals, bplan)
+
+
+def requests_from_lanes(vals, bplan) -> List[Tuple[int, List[List[int]]]]:
+    """(req_id, prompts) per message from the decoded :data:`REQUEST_PATHS`
+    lanes (jnp ``decode_batch`` or the Pallas ``decode_batch_kernel``).
 
     The per-prompt lengths are read from the decoded *count fields* of the
     inner token lists (container paths decode like u32 leaves), so splitting
     the flat token column back into prompts needs no second walk.
     """
-    schema = request_schema()
-    # only these three leaves are consumed; skipping the outer 'prompts'
-    # count leaf drops one gather from the request hot path
-    paths = ["req_id", "prompts.elem.tokens", "prompts.elem.tokens.elem"]
-    bplan = batch_plans(schema, wires, record_paths=paths)
-    vals = decode_batch(jnp.asarray(stack_wires(wires)), bplan)
     rid_lanes = np.asarray(vals["req_id"])  # (N, 1, 2)
     len_lanes = np.asarray(vals["prompts.elem.tokens"])  # (N, capP, 1)
     tok_lanes = np.asarray(vals["prompts.elem.tokens.elem"])  # (N, capT, 1)
     out = []
-    for m in range(len(wires)):
+    for m in range(bplan.n_messages):
         rid = int(lanes_to_int(rid_lanes[m], 8)[0])
         n_prompts = int(bplan.counts["prompts.elem.tokens"][m])
         n_toks = int(bplan.counts["prompts.elem.tokens.elem"][m])
@@ -338,6 +346,17 @@ def default_serve_fabric(
     return fab
 
 
+def _require_shards(fabric, caller: str) -> None:
+    """The routed planes need an ingress plus at least one shard."""
+    ranks = 0 if fabric is None else fabric.n_ranks
+    if ranks < 2:
+        raise ValueError(
+            f"{caller} needs a fabric of >= 2 ranks (ingress + shards), got "
+            f"{ranks} ({len(jax.devices())} device(s) visible); serve on one "
+            f"device with serve_requests"
+        )
+
+
 def serve_requests_sharded(
     params,
     cfg,
@@ -385,17 +404,13 @@ def serve_requests_sharded(
     same bytes and the answer stays byte-identical; a request whose retry
     also dies raises.  ``suspect_after=None`` disables the detector.
 
-    Falls back to the local batched plane when the fabric would have fewer
-    than 2 ranks (no shard to route to).
+    Raises ``ValueError`` when the fabric has fewer than 2 ranks (no shard
+    to route to); one device serves through ``serve_requests``.
     """
     if fabric is None:
         fabric = default_serve_fabric(n_shards, routing=routing,
                                       defect_after=defect_after)
-    if fabric is None or fabric.n_ranks < 2:
-        return serve_requests(
-            params, cfg, wires, max_new=max_new, pad_to=pad_to,
-            slots=slots, admit_cap=admit_cap,
-        )
+    _require_shards(fabric, "serve_requests_sharded")
     if metrics is not None:
         fabric.metrics = metrics
     if trace is not None:
@@ -598,8 +613,8 @@ def serve_requests_streaming(
 
     Returns the final response wires, byte-identical to ``serve_requests``
     on the same inputs (the streamed tokens are re-serialized through the
-    same bulk SER).  Falls back to the local batched plane (no streaming
-    events) when the fabric would have fewer than 2 ranks.
+    same bulk SER).  Raises ``ValueError`` when the fabric has fewer than 2
+    ranks; one device serves through ``serve_requests``.
 
     ``metrics`` (an ``obs.metrics.MetricsRegistry``) turns on serve-level
     telemetry — per-stream TTFT (``serve.ttft_s``), per-tick token rate
@@ -651,11 +666,7 @@ def serve_requests_streaming(
     if fabric is None:
         fabric = default_serve_fabric(n_shards, routing=routing,
                                       defect_after=defect_after)
-    if fabric is None or fabric.n_ranks < 2:
-        return serve_requests(
-            params, cfg, wires, max_new=max_new, pad_to=pad_to,
-            slots=slots, admit_cap=admit_cap,
-        )
+    _require_shards(fabric, "serve_requests_streaming")
     if metrics is not None:
         fabric.metrics = metrics  # one registry across the whole stack
     if trace is not None:
@@ -757,6 +768,11 @@ def serve_requests_streaming(
             batcher = ContinuousBatcher(params, cfg, sched, metrics=metrics,
                                         spans=spans, logprobs=logprobs)
             batchers[s] = batcher
+            if metrics is not None:
+                # the device holding this shard's slot cache, where its
+                # prefill and decode steps run
+                (dev,) = jax.tree.leaves(batcher.cache)[0].devices()
+                metrics.gauge("serve.shard.device", shard=s).set(dev.id)
         for d, (_, prompts) in zip(arrived, local_reqs):
             k = admitted[s]
             admitted[s] += 1
@@ -1126,6 +1142,7 @@ def main() -> None:
                          "repro.obs.slo) and exit 1 on any violation")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     metrics = trace = spans = None
     if args.metrics_json or args.trace_out or args.slo or args.attribution_json:
@@ -1151,9 +1168,6 @@ def main() -> None:
             args.n_shards, routing=args.routing,
             defect_after=args.defect_after, arq=not args.no_arq,
             faults=faults)
-        if args.chaos and serve_fabric is None:
-            raise SystemExit("--chaos needs a multi-rank fabric "
-                             "(>= 2 visible devices)")
     suspect_after = args.suspect_after if args.suspect_after > 0 else None
 
     rng = np.random.default_rng(args.seed)

@@ -47,9 +47,7 @@ def pod_ring_exchange(
     """ppermute a framed stream one hop around `axis_name` (call under
     shard_map).  The framed stream is self-describing, so the receiver can
     decode without out-of-band length metadata — the paper's point."""
-    # NB: jax.lax.axis_size does not exist in the pinned JAX; psum of ones
-    # over the axis is the portable way to recover its size inside shard_map.
-    n = int(jax.lax.psum(1, axis_name))
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(frames, axis_name, perm)
 
@@ -62,7 +60,6 @@ def make_framed_sender(mesh: Mesh, axis_name: str, frame_phits: int = FRAME_PHIT
     (payload, nbytes, ok) with the same layout.  The framed stream is
     self-describing, so no out-of-band length metadata crosses the link.
     """
-    from jax.experimental.shard_map import shard_map
 
     def send(payload_u32, nbytes):
         frames, _ = frame_stream(
@@ -72,10 +69,10 @@ def make_framed_sender(mesh: Mesh, axis_name: str, frame_phits: int = FRAME_PHIT
         p, nb, ok = unframe_stream(out)
         return p[None], nb[None], ok[None]
 
-    return shard_map(
+    return jax.shard_map(
         send,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name)),
         out_specs=(P(axis_name), P(axis_name), P(axis_name)),
-        check_rep=False,
+        check_vma=False,
     )

@@ -17,7 +17,6 @@ from typing import Any, Callable, List
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -87,12 +86,12 @@ def gpipe_forward(
         # only the last stage's buffer holds real outputs (caller slices)
         return buf[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P(None)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(stage_params, x[None])
     # row s of `out` is stage s's buffer; the final outputs live in the last

@@ -4,8 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import (
-    decode_gather, decode_message_kernel, decode_run, encode_run,
-    wire_to_u32, write_headers,
+    decode_gather, decode_message_kernel, decode_run, encode_run, wire_to_u32,
 )
 from repro.kernels import ref
 from repro.kernels.ops import runs_from_plan
@@ -54,14 +53,6 @@ def test_pack_unpack_roundtrip(rng):
     wire = encode_run(toks, 16, 16)
     back = decode_run(wire, 0, 16, 300, 16)
     np.testing.assert_array_equal(np.asarray(back), np.asarray(toks))
-
-
-def test_stamp_headers(rng):
-    w32 = wire_to_u32(rng.integers(0, 256, 4096, dtype=np.uint8).tobytes())
-    hdr = np.array([[0, 100, 1], [128, 0, 2], [512, 64, 1], [1000, 4, 3]], np.int32)
-    got = write_headers(w32, jnp.asarray(hdr))
-    want = ref.stamp_headers_ref(w32, hdr)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_decode_message_kernel_end_to_end(rng):

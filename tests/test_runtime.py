@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import all_archs, get_config, smoke_config
+from repro.launch.mesh import make_debug_mesh
 from repro.models import init_cache, init_params
 from repro.runtime import (
     ShardRules, batch_pspec, cache_shardings,
@@ -20,7 +20,7 @@ from repro.runtime import (
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_debug_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("arch", all_archs())
@@ -91,8 +91,8 @@ def test_int8_cross_pod_mean(mesh):
         return (jax.tree.map(lambda x: x[None], m),
                 jax.tree.map(lambda x: x[None], en))
 
-    f = shard_map(red, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                  out_specs=(P("pod"), P("pod")), check_rep=False)
+    f = jax.shard_map(red, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                  out_specs=(P("pod"), P("pod")), check_vma=False)
     m, en = jax.jit(f)(g, e)
     np.testing.assert_allclose(np.asarray(m["w"])[0], 2.0, atol=0.05)
     # error feedback: residual bounded by one quantization step
@@ -126,7 +126,7 @@ def test_gpipe_matches_reference(mesh):
         return x
 
     x = jax.random.normal(k, (4, 2, 6, 8))
-    pm = jax.make_mesh((2,), ("pod",), devices=jax.devices()[:2])
+    pm = make_debug_mesh((2,), ("pod",), devices=jax.devices()[:2])
     y = gpipe_forward(pm, "pod", stage_fn, sp, x)
     ref = x
     for s in range(2):

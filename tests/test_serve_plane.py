@@ -21,7 +21,8 @@ from repro.data.schemas import request_schema
 from repro.kernels.ops import decode_batch_kernel, wires_to_u32
 from repro.launch.serve import (
     decode_request, decode_request_batch, decode_response, encode_request,
-    serve_request, serve_requests,
+    serve_request, serve_requests, serve_requests_sharded,
+    serve_requests_streaming,
 )
 
 
@@ -173,3 +174,14 @@ def test_scheduler_other_families(arch, rng):
     resp = serve_requests(params, cfg, wires, max_new=3, pad_to=8, slots=2)
     rid, outs = decode_response(resp[0])
     assert rid == 0 and len(outs) == 1 and len(outs[0]) == 3
+
+
+@pytest.mark.parametrize("serve", [serve_requests_sharded, serve_requests_streaming])
+def test_routed_planes_refuse_one_rank(serve):
+    """Below 2 ranks there is no shard to route to: the routed planes raise
+    instead of quietly serving the batched plane under their own name."""
+    from repro.fabric import Fabric
+
+    wires = [encode_request(1, [[5, 6, 7]])]
+    with pytest.raises(ValueError, match=">= 2 ranks"):
+        serve(None, None, wires, fabric=Fabric(n_ranks=1))
