@@ -105,6 +105,7 @@ from ..obs.counters import (
     observed_link_loads as _observed_link_loads,
 )
 from ..obs.metrics import ClassWindows, MetricsRegistry
+from ..obs.trace import span
 from .faults import FaultPlan
 from .frames import (
     HDR_CRC,
@@ -422,111 +423,112 @@ class Fabric:
         in-flight tick is completed first, so per-stream FIFO order holds.
         Returns True when a tick was dispatched (False: nothing pending).
         """
-        if self._inflight is not None:
-            self._complete()
-        # virtual clock: advances on every call (idle ticks included) so
-        # ARQ timeouts and the serve plane's blackout detector measure
-        # elapsed fabric time, not message counts
-        self.ticks += 1
-        if self.config.arq:
-            # may queue retransmits (sender timeouts), re-NACKs, owed
-            # ACKs, and gap skips into _pending — BEFORE the empty check,
-            # so recovery traffic flows even when the app has nothing to
-            # say
-            self._arq_tick()
-        if not self._pending:
-            return False
-        if self.analyze:
-            # static pre-flight of this tick's demand: rank ranges, seq
-            # windows, rx capacity — raise with the rule's fix hint BEFORE
-            # dispatch (the pending sends stay queued, so the caller can
-            # drop the offender and retry)
-            from ..analysis.fabric_passes import analyze_sends
-            from ..analysis.findings import assert_clean
-
-            _, fs = analyze_sends(
-                self.router.sizes, self.config, self._pending,
-            )
-            assert_clean(fs, "Fabric.exchange(analyze=True)")
-        sends, self._pending = self._pending, []
-        metas, self._pending_meta = self._pending_meta, []
-        if len(metas) != len(sends):  # a test poked _pending directly
-            metas = (metas + [{}] * len(sends))[: len(sends)]
-        phits = self.config.frame_phits
-        frame_words = phits * PHIT_WORDS
-        B = len(sends)
-        n_live = [frame_capacity(len(w), phits) for _, _, w, _ in sends]
-        # bucket the payload frame capacity (pow2) so the jitted batched
-        # SER pass is reused across ticks with varying wire lengths
-        pf = 1 << max(max(n_live) - 2, 0).bit_length()  # payload frames
-        cap_words = pf * frame_words
-        F_arr = pf + 1  # + terminator: frames emitted per stream
-        payloads = np.stack([_wire_words(w, cap_words) for _, _, w, _ in sends])
-        nbytes = np.asarray([len(w) for _, _, w, _ in sends], np.int32)
-        routes = np.zeros((B, 3), np.int32)
-        for i, (src, dst, _, _) in enumerate(sends):
-            m = metas[i]
-            if m.get("ctl"):
-                # control frames: own seq space, never buffered, never
-                # span-correlated (each ctl payload frame is parsed
-                # standalone by magic — the receiver ignores ctl seqs)
-                seq0 = self._tx_seq_ctl[src][dst]
-                self._tx_seq_ctl[src][dst] = (seq0 + n_live[i]) % SEQ_MOD
-                routes[i] = (src, dst, seq0)
-                continue
-            if m.get("seq0") is not None:
-                # ARQ retransmit: the message keeps its ORIGINAL seq range
-                # (no counter advance, no re-registration — the original
-                # retx entry and span registration still stand)
-                routes[i] = (src, dst, int(m["seq0"]))
-                continue
-            seq0 = self._tx_seq[src][dst]
-            routes[i] = (src, dst, seq0)
-            self._tx_seq[src][dst] = (seq0 + n_live[i]) % SEQ_MOD
+        with span("fabric.exchange"):
+            if self._inflight is not None:
+                self._complete()
+            # virtual clock: advances on every call (idle ticks included) so
+            # ARQ timeouts and the serve plane's blackout detector measure
+            # elapsed fabric time, not message counts
+            self.ticks += 1
             if self.config.arq:
-                self._retx_register(src, dst, seq0, n_live[i], sends[i][2],
-                                    sends[i][3], m.get("rid"))
-            if m.get("rid") is not None:
-                # rid correlation: the message owns seqs [seq0, seq0+n) of
-                # the (src -> dst) stream; reassembly matches the first
-                # delivered frame's seq into this range
-                self._send_spans.setdefault((dst, src), []).append(
-                    (seq0, n_live[i], m["rid"])
-                )
+                # may queue retransmits (sender timeouts), re-NACKs, owed
+                # ACKs, and gap skips into _pending — BEFORE the empty check,
+                # so recovery traffic flows even when the app has nothing to
+                # say
+                self._arq_tick()
+            if not self._pending:
+                return False
+            if self.analyze:
+                # static pre-flight of this tick's demand: rank ranges, seq
+                # windows, rx capacity — raise with the rule's fix hint BEFORE
+                # dispatch (the pending sends stay queued, so the caller can
+                # drop the offender and retry)
+                from ..analysis.fabric_passes import analyze_sends
+                from ..analysis.findings import assert_clean
 
-        # accumulate the tick's STATIC demand matrix (what the analyzer
-        # predicts this traffic should put on every (link, direction)) so
-        # `load_drift()` can hold it against the on-device observed side
-        self._note_expected(sends, n_live)
-        self._inflight_meta = {
-            "frames": sum(n_live),
-            "sends": len(sends),
-            "t0": self.trace.now_us() if self.trace is not None else 0.0,
-        }
-        # seeded chaos: ONE post-fault frame list per rank, consumed by
-        # whichever engine dispatches below — injection dynamics are
-        # engine-independent by construction
-        fault_lists = self._plan_frame_faults(sends, n_live, routes)
-        if self.config.fused and self.tx_hook is None:
-            self._dispatch_fused(sends, n_live, payloads, nbytes, routes,
-                                 F_arr, fault_lists)
-        else:
-            fill = [0] * self.n_ranks
-            if fault_lists is not None:
-                for r, post in enumerate(fault_lists):
-                    fill[r] = len(post)
+                _, fs = analyze_sends(
+                    self.router.sizes, self.config, self._pending,
+                )
+                assert_clean(fs, "Fabric.exchange(analyze=True)")
+            sends, self._pending = self._pending, []
+            metas, self._pending_meta = self._pending_meta, []
+            if len(metas) != len(sends):  # a test poked _pending directly
+                metas = (metas + [{}] * len(sends))[: len(sends)]
+            phits = self.config.frame_phits
+            frame_words = phits * PHIT_WORDS
+            B = len(sends)
+            n_live = [frame_capacity(len(w), phits) for _, _, w, _ in sends]
+            # bucket the payload frame capacity (pow2) so the jitted batched
+            # SER pass is reused across ticks with varying wire lengths
+            pf = 1 << max(max(n_live) - 2, 0).bit_length()  # payload frames
+            cap_words = pf * frame_words
+            F_arr = pf + 1  # + terminator: frames emitted per stream
+            payloads = np.stack([_wire_words(w, cap_words) for _, _, w, _ in sends])
+            nbytes = np.asarray([len(w) for _, _, w, _ in sends], np.int32)
+            routes = np.zeros((B, 3), np.int32)
+            for i, (src, dst, _, _) in enumerate(sends):
+                m = metas[i]
+                if m.get("ctl"):
+                    # control frames: own seq space, never buffered, never
+                    # span-correlated (each ctl payload frame is parsed
+                    # standalone by magic — the receiver ignores ctl seqs)
+                    seq0 = self._tx_seq_ctl[src][dst]
+                    self._tx_seq_ctl[src][dst] = (seq0 + n_live[i]) % SEQ_MOD
+                    routes[i] = (src, dst, seq0)
+                    continue
+                if m.get("seq0") is not None:
+                    # ARQ retransmit: the message keeps its ORIGINAL seq range
+                    # (no counter advance, no re-registration — the original
+                    # retx entry and span registration still stand)
+                    routes[i] = (src, dst, int(m["seq0"]))
+                    continue
+                seq0 = self._tx_seq[src][dst]
+                routes[i] = (src, dst, seq0)
+                self._tx_seq[src][dst] = (seq0 + n_live[i]) % SEQ_MOD
+                if self.config.arq:
+                    self._retx_register(src, dst, seq0, n_live[i], sends[i][2],
+                                        sends[i][3], m.get("rid"))
+                if m.get("rid") is not None:
+                    # rid correlation: the message owns seqs [seq0, seq0+n) of
+                    # the (src -> dst) stream; reassembly matches the first
+                    # delivered frame's seq into this range
+                    self._send_spans.setdefault((dst, src), []).append(
+                        (seq0, n_live[i], m["rid"])
+                    )
+
+            # accumulate the tick's STATIC demand matrix (what the analyzer
+            # predicts this traffic should put on every (link, direction)) so
+            # `load_drift()` can hold it against the on-device observed side
+            self._note_expected(sends, n_live)
+            self._inflight_meta = {
+                "frames": sum(n_live),
+                "sends": len(sends),
+                "t0": self.trace.now_us() if self.trace is not None else 0.0,
+            }
+            # seeded chaos: ONE post-fault frame list per rank, consumed by
+            # whichever engine dispatches below — injection dynamics are
+            # engine-independent by construction
+            fault_lists = self._plan_frame_faults(sends, n_live, routes)
+            if self.config.fused and self.tx_hook is None:
+                self._dispatch_fused(sends, n_live, payloads, nbytes, routes,
+                                     F_arr, fault_lists)
             else:
-                for i, (src, _, _, _) in enumerate(sends):
-                    fill[src] += n_live[i]
-            T = max(1, max(fill))
-            T = 1 << (T - 1).bit_length()  # bucket for router jit reuse
-            total = self.router.bucket_total(sum(fill), T)
-            self._dispatch_programs(
-                sends, n_live, payloads, nbytes, routes, T, total,
-                pf, frame_words, fault_lists,
-            )
-        self.exchanges += 1
-        return True
+                fill = [0] * self.n_ranks
+                if fault_lists is not None:
+                    for r, post in enumerate(fault_lists):
+                        fill[r] = len(post)
+                else:
+                    for i, (src, _, _, _) in enumerate(sends):
+                        fill[src] += n_live[i]
+                T = max(1, max(fill))
+                T = 1 << (T - 1).bit_length()  # bucket for router jit reuse
+                total = self.router.bucket_total(sum(fill), T)
+                self._dispatch_programs(
+                    sends, n_live, payloads, nbytes, routes, T, total,
+                    pf, frame_words, fault_lists,
+                )
+            self.exchanges += 1
+            return True
 
     def _plan_frame_faults(self, sends, n_live, routes):
         """Roll the seeded :class:`FaultPlan` over this tick's logical
@@ -731,51 +733,53 @@ class Fabric:
         """RX readback + reassembly of the in-flight tick (the host half of
         the exchange, deferred by ``exchange_async``).  This is the ONLY
         point where delivered frames are materialized as host bytes."""
-        kind, *out = self._inflight
-        self._inflight = None
-        meta, self._inflight_meta = self._inflight_meta or {}, None
-        if kind == "fused":  # RX split already happened inside the tick jit
-            rx_hdr, rx_pay, rx_cnt, ok, crc_ok, rx_step, rx_att, ctr = out
-        else:
-            rx, rx_cnt, ok, crc_ok, rx_step, rx_att, ctr = out
-        self.last_crc_ok = bool(np.all(np.asarray(crc_ok)))
-        # counter readback rides the SAME host sync this reassembly already
-        # pays — the dispatch path stays sync-free with counters on
-        self._fold_counters(np.asarray(ctr), kind, meta)
-        if not bool(np.all(np.asarray(ok))):
-            raise RuntimeError(
-                "fabric routing failed (undeliverable frame or buffer "
-                "overflow) — check ranks and FabricConfig capacities"
-            )
-        self.frames_routed += int(np.sum(np.asarray(rx_cnt)))
-        rx_step = np.asarray(rx_step)
-        rx_att = np.asarray(rx_att)
-        counts = [int(c) for c in np.asarray(rx_cnt)]
-        if not any(counts):
-            return
-        steps = np.concatenate([rx_step[r, :c] for r, c in enumerate(counts) if c])
-        atts = np.concatenate([rx_att[r, :c] for r, c in enumerate(counts) if c])
-        if kind == "fused":
-            rx_hdr, rx_pay = np.asarray(rx_hdr), np.asarray(rx_pay)
-            hdrs = np.concatenate([rx_hdr[r, :c] for r, c in enumerate(counts) if c])
-            pays = np.concatenate([rx_pay[r, :c] for r, c in enumerate(counts) if c])
-        else:
-            # RX split on the Pallas kernel twin: one batched call separates
-            # every delivered frame into header + payload rows
-            rx = np.asarray(rx)
-            flat = np.concatenate([rx[r, :c] for r, c in enumerate(counts) if c])
-            hdrs, pays = self._split_bucketed(flat)
-        reassemble = (
-            self._reassemble_arq if self.config.arq else self._reassemble
-        )
-        off = 0
-        for r, c in enumerate(counts):
-            if c:
-                reassemble(
-                    r, hdrs[off : off + c], pays[off : off + c],
-                    steps[off : off + c], atts[off : off + c],
+        frames = (self._inflight_meta or {}).get("frames", 0)
+        with span("fabric.complete", frames=frames):
+            kind, *out = self._inflight
+            self._inflight = None
+            meta, self._inflight_meta = self._inflight_meta or {}, None
+            if kind == "fused":  # RX split already happened inside the tick jit
+                rx_hdr, rx_pay, rx_cnt, ok, crc_ok, rx_step, rx_att, ctr = out
+            else:
+                rx, rx_cnt, ok, crc_ok, rx_step, rx_att, ctr = out
+            self.last_crc_ok = bool(np.all(np.asarray(crc_ok)))
+            # counter readback rides the SAME host sync this reassembly already
+            # pays — the dispatch path stays sync-free with counters on
+            self._fold_counters(np.asarray(ctr), kind, meta)
+            if not bool(np.all(np.asarray(ok))):
+                raise RuntimeError(
+                    "fabric routing failed (undeliverable frame or buffer "
+                    "overflow) — check ranks and FabricConfig capacities"
                 )
-                off += c
+            self.frames_routed += int(np.sum(np.asarray(rx_cnt)))
+            rx_step = np.asarray(rx_step)
+            rx_att = np.asarray(rx_att)
+            counts = [int(c) for c in np.asarray(rx_cnt)]
+            if not any(counts):
+                return
+            steps = np.concatenate([rx_step[r, :c] for r, c in enumerate(counts) if c])
+            atts = np.concatenate([rx_att[r, :c] for r, c in enumerate(counts) if c])
+            if kind == "fused":
+                rx_hdr, rx_pay = np.asarray(rx_hdr), np.asarray(rx_pay)
+                hdrs = np.concatenate([rx_hdr[r, :c] for r, c in enumerate(counts) if c])
+                pays = np.concatenate([rx_pay[r, :c] for r, c in enumerate(counts) if c])
+            else:
+                # RX split on the Pallas kernel twin: one batched call separates
+                # every delivered frame into header + payload rows
+                rx = np.asarray(rx)
+                flat = np.concatenate([rx[r, :c] for r, c in enumerate(counts) if c])
+                hdrs, pays = self._split_bucketed(flat)
+            reassemble = (
+                self._reassemble_arq if self.config.arq else self._reassemble
+            )
+            off = 0
+            for r, c in enumerate(counts):
+                if c:
+                    reassemble(
+                        r, hdrs[off : off + c], pays[off : off + c],
+                        steps[off : off + c], atts[off : off + c],
+                    )
+                    off += c
 
     @staticmethod
     def _encode_bucketed(payloads, nbytes, routes, list_level, phits,

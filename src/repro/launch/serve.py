@@ -61,6 +61,7 @@ from ..core import (
 )
 from ..data.schemas import request_schema, response_schema
 from ..models import init_params
+from ..obs.trace import span
 from ..runtime.scheduler import ContinuousBatcher, SchedulerConfig
 from .compile_cache import use_compile_cache
 from .steps import make_prefill_step, make_serve_step
@@ -88,9 +89,13 @@ REQUEST_PATHS = ["req_id", "prompts.elem.tokens", "prompts.elem.tokens.elem"]
 
 def decode_request_batch(wires: List[bytes]) -> List[Tuple[int, List[List[int]]]]:
     """Batched DES of N request wires: one schema walk + one gather per leaf."""
-    bplan = batch_plans(request_schema(), wires, record_paths=REQUEST_PATHS)
-    vals = decode_batch(jnp.asarray(stack_wires(wires)), bplan)
-    return requests_from_lanes(vals, bplan)
+    with span("serve.des", wires=len(wires)):
+        with span("des.plan"):
+            bplan = batch_plans(request_schema(), wires, record_paths=REQUEST_PATHS)
+        with span("des.gather"):
+            vals = jax.device_get(decode_batch(jnp.asarray(stack_wires(wires)), bplan))
+        with span("des.split"):
+            return requests_from_lanes(vals, bplan)
 
 
 def requests_from_lanes(vals, bplan) -> List[Tuple[int, List[List[int]]]]:
@@ -125,14 +130,15 @@ def encode_response_batch(
     responses: List[Tuple[int, List[List[int]]]]
 ) -> List[bytes]:
     """Bulk HW->SW SER: one schema ROM shared by every response wire."""
-    schema = response_schema()
-    rom = build_rom(schema)
-    wires = []
-    for req_id, outputs in responses:
-        msg = {"req_id": req_id, "outputs": [{"tokens": o} for o in outputs]}
-        toks = strip_for_ser(msg_to_des_tokens(schema, msg))
-        wires.append(SerFSM(rom, "hw2sw").run(toks).wire)
-    return wires
+    with span("serve.ser", wires=len(responses)):
+        schema = response_schema()
+        rom = build_rom(schema)
+        wires = []
+        for req_id, outputs in responses:
+            msg = {"req_id": req_id, "outputs": [{"tokens": o} for o in outputs]}
+            toks = strip_for_ser(msg_to_des_tokens(schema, msg))
+            wires.append(SerFSM(rom, "hw2sw").run(toks).wire)
+        return wires
 
 
 def decode_response(wire: bytes) -> Tuple[int, List[List[int]]]:
@@ -894,84 +900,85 @@ def serve_requests_streaming(
     tok_count = [0, 0]  # [total tokens arrived, tokens this tick]
 
     def _pump() -> None:
-        got = ingress.recv()
-        if lp_reader is not None:
-            # the reserved ListLevel partitions the two typed streams
-            lp_got = [d for d in got if d.list_level == LOGPROB_STREAM_LEVEL]
-            got = [d for d in got if d.list_level != LOGPROB_STREAM_LEVEL]
-            for ev in lp_reader.feed(lp_got):
+        with span("stream.pump"):
+            got = ingress.recv()
+            if lp_reader is not None:
+                # the reserved ListLevel partitions the two typed streams
+                lp_got = [d for d in got if d.list_level == LOGPROB_STREAM_LEVEL]
+                got = [d for d in got if d.list_level != LOGPROB_STREAM_LEVEL]
+                for ev in lp_reader.feed(lp_got):
+                    key = (ev.src, ev.stream_id)
+                    if key in abandoned:
+                        continue  # stale side-stream of a retried request
+                    if not ev.ok:
+                        raise RuntimeError(
+                            f"ingress: corrupt logprob stream chunks from "
+                            f"shard {ev.src}"
+                        )
+                    if on_logprob is not None:
+                        k, j = ev.stream_id >> 16, ev.stream_id & 0xFFFF
+                        m = globals_of[ev.src][k]
+                        for t, (tok, bits) in enumerate(ev.tokens):
+                            lpv = float(np.uint32(bits).view(np.float32))
+                            on_logprob(m, j, ev.step + t, int(tok), lpv)
+            for ev in reader.feed(got):
                 key = (ev.src, ev.stream_id)
                 if key in abandoned:
-                    continue  # stale side-stream of a retried request
+                    continue  # stale chunks from a suspect shard's old stream
                 if not ev.ok:
                     raise RuntimeError(
-                        f"ingress: corrupt logprob stream chunks from "
-                        f"shard {ev.src}"
+                        f"ingress: corrupt stream chunks from shard {ev.src}"
                     )
-                if on_logprob is not None:
+                tok_count[0] += len(ev.tokens)
+                tok_count[1] += len(ev.tokens)
+                if ev.tokens and key not in seen_first:
+                    seen_first.add(key)
+                    ttft = time.perf_counter() - t_serve0
+                    if metrics is not None:
+                        metrics.histogram("serve.ttft_s", base=0.001).observe(ttft)
+                        metrics.series("serve.ttft_s.series").append(ttft)
+                    if spans is not None and key in reader.span_ids:
+                        spans.event(reader.span_ids[key], "serve.first_token",
+                                    ttft_s=ttft)
+                if ev.eos and spans is not None and key in reader.span_ids:
+                    rid = reader.span_ids[key]
+                    open_streams[rid] = open_streams.get(rid, 1) - 1
+                    if open_streams[rid] <= 0:
+                        spans.finish(rid)
+                if trace is not None:
+                    trace.instant(
+                        "stream.chunk", cat="stream", pid=ev.src,
+                        args={"stream": ev.stream_id, "step": ev.step,
+                              "tokens": len(ev.tokens),
+                              "arrive_step": ev.arrive_step},
+                    )
+                if on_event is not None:
+                    on_event(ev)
+                if on_token is not None:
                     k, j = ev.stream_id >> 16, ev.stream_id & 0xFFFF
                     m = globals_of[ev.src][k]
-                    for t, (tok, bits) in enumerate(ev.tokens):
-                        lpv = float(np.uint32(bits).view(np.float32))
-                        on_logprob(m, j, ev.step + t, int(tok), lpv)
-        for ev in reader.feed(got):
-            key = (ev.src, ev.stream_id)
-            if key in abandoned:
-                continue  # stale chunks from a suspect shard's old stream
-            if not ev.ok:
-                raise RuntimeError(
-                    f"ingress: corrupt stream chunks from shard {ev.src}"
-                )
-            tok_count[0] += len(ev.tokens)
-            tok_count[1] += len(ev.tokens)
-            if ev.tokens and key not in seen_first:
-                seen_first.add(key)
-                ttft = time.perf_counter() - t_serve0
-                if metrics is not None:
-                    metrics.histogram("serve.ttft_s", base=0.001).observe(ttft)
-                    metrics.series("serve.ttft_s.series").append(ttft)
-                if spans is not None and key in reader.span_ids:
-                    spans.event(reader.span_ids[key], "serve.first_token",
-                                ttft_s=ttft)
-            if ev.eos and spans is not None and key in reader.span_ids:
-                rid = reader.span_ids[key]
-                open_streams[rid] = open_streams.get(rid, 1) - 1
-                if open_streams[rid] <= 0:
-                    spans.finish(rid)
-            if trace is not None:
-                trace.instant(
-                    "stream.chunk", cat="stream", pid=ev.src,
-                    args={"stream": ev.stream_id, "step": ev.step,
-                          "tokens": len(ev.tokens),
-                          "arrive_step": ev.arrive_step},
-                )
-            if on_event is not None:
-                on_event(ev)
-            if on_token is not None:
-                k, j = ev.stream_id >> 16, ev.stream_id & 0xFFFF
-                m = globals_of[ev.src][k]
-                for t, tok in enumerate(ev.tokens):
-                    on_token(m, j, ev.step + t, tok)
-        per_class = (
-            reader.class_arrive_stats(window=64)
-            if (backpressure_p95 is not None or metrics is not None)
-            else {}
-        )
-        if metrics is not None:
-            # the live backpressure feedback values, recorded whether or
-            # not a threshold acts on them — the observability of the loop
-            # must not depend on the loop being closed
-            for cls, st in per_class.items():
-                metrics.series("serve.backpressure.p95",
-                               cls=cls).append(st["p95"])
-        if backpressure_p95 is not None:
-            # close the loop: the reader's per-class p95 arrive latency
-            # clamps (or releases) each lane's flush rate for next tick;
-            # the sliding window lets a clamped tenant recover once its
-            # congested tail has drained
-            for lane in lanes.values():
-                st = per_class.get(lane.list_level)
-                lane.feedback(st["p95"] if st else None)
+                    for t, tok in enumerate(ev.tokens):
+                        on_token(m, j, ev.step + t, tok)
+            per_class = (
+                reader.class_arrive_stats(window=64)
+                if (backpressure_p95 is not None or metrics is not None)
+                else {}
+            )
+            if metrics is not None:
+                # the live backpressure feedback values, recorded whether or
+                # not a threshold acts on them — the observability of the loop
+                # must not depend on the loop being closed
+                for cls, st in per_class.items():
+                    metrics.series("serve.backpressure.p95",
+                                   cls=cls).append(st["p95"])
+            if backpressure_p95 is not None:
+                # close the loop: the reader's per-class p95 arrive latency
+                # clamps (or releases) each lane's flush rate for next tick;
+                # the sliding window lets a clamped tenant recover once its
+                # congested tail has drained
+                for lane in lanes.values():
+                    st = per_class.get(lane.list_level)
+                    lane.feedback(st["p95"] if st else None)
 
     tick = 0
     idle = 0
@@ -992,44 +999,43 @@ def serve_requests_streaming(
         if active:
             idle = 0
             force_flushed = False
-            t_tick0 = trace.now_us() if trace is not None else 0.0
             tok_count[1] = 0
-            for b in batchers.values():
-                b.step_begin()  # dispatch compute; device runs in background
-            if overlap:
-                fabric.poll()  # reap last tick's chunks while decode runs
-                _pump()
-            for s, b in list(batchers.items()):
-                for (k, j), pos, tok in b.step_finish():
-                    eos = pos == max_new - 1
-                    writers[(s, k, j)].write((tok,), eos=eos)
-                    if logprobs:
-                        # the logprob element is (tok, float32 bit
-                        # pattern) — the schema's Struct{tok, logprob}
-                        bits = int(np.float32(
-                            b.tick_logprobs[((k, j), pos)]
-                        ).view(np.uint32))
-                        lp_writers[(s, k, j)].write(((tok, bits),), eos=eos)
-            for lane in lanes.values():
-                lane.flush()  # ONE burst per (shard, tenant) this tick
-            if overlap:
-                fabric.exchange_async()  # dispatch routing; overlap next tick
-            else:
-                fabric.exchange()
-                _pump()
-            if metrics is not None:
-                metrics.series("serve.tick.tokens").append(tok_count[1])
-            if trace is not None:
-                trace.complete("serve.tick", t_tick0,
-                               trace.now_us() - t_tick0, cat="serve",
-                               args={"tokens_arrived": tok_count[1]})
+            with span("serve.tick", trace, tick=tick) as tick_span:
+                for b in batchers.values():
+                    b.step_begin()  # dispatch compute; device runs in background
+                if overlap:
+                    fabric.poll()  # reap last tick's chunks while decode runs
+                    _pump()
+                for s, b in list(batchers.items()):
+                    for (k, j), pos, tok in b.step_finish():
+                        eos = pos == max_new - 1
+                        writers[(s, k, j)].write((tok,), eos=eos)
+                        if logprobs:
+                            # the logprob element is (tok, float32 bit
+                            # pattern) — the schema's Struct{tok, logprob}
+                            bits = int(np.float32(
+                                b.tick_logprobs[((k, j), pos)]
+                            ).view(np.uint32))
+                            lp_writers[(s, k, j)].write(((tok, bits),), eos=eos)
+                with span("stream.flush"):
+                    for lane in lanes.values():
+                        lane.flush()  # ONE burst per (shard, tenant) this tick
+                if overlap:
+                    fabric.exchange_async()  # dispatch routing; overlap next tick
+                else:
+                    fabric.exchange()
+                    _pump()
+                if metrics is not None:
+                    metrics.series("serve.tick.tokens").append(tok_count[1])
+                tick_span.args["tokens_arrived"] = tok_count[1]
         else:
             # nothing left to compute: force out any bursts a clamped
             # lane still holds, then keep the fabric ticking so in-flight
             # chunks, ARQ recovery traffic, and retried request wires land
             if not force_flushed:
-                for lane in lanes.values():
-                    lane.flush(force=True)
+                with span("stream.flush"):
+                    for lane in lanes.values():
+                        lane.flush(force=True)
                 force_flushed = True
             idle += 1
             if idle > drain_cap:

@@ -19,10 +19,14 @@ Four layers (see ISSUE/README "Observability"):
   mailbox / batcher / stream lanes / serve as one connected Perfetto
   arc, and declared latency/throughput targets evaluate against
   snapshots with burn-rate output;
-* **export** (:mod:`repro.obs.trace`, :mod:`repro.obs.report`):
-  Chrome-trace JSON timelines, text/JSON metric reports, snapshot
-  diffs, attribution tables, plus ``python -m repro.obs`` to summarize,
-  ``--validate``, ``diff``, ``attribution``, ``slo``, or ``history``.
+* **spans and export** (:mod:`repro.obs.trace`, :mod:`repro.obs.report`):
+  :func:`~repro.obs.trace.span`, the serve path's host spans, written into
+  the ``jax.profiler`` trace on the device's clock whenever one is being
+  captured (and into a recorder's timeline when one is passed);
+  Chrome-trace JSON timelines on the recorder's own ``perf_counter``
+  clock, text/JSON metric reports, snapshot diffs, attribution tables,
+  plus ``python -m repro.obs`` to summarize, ``--validate``, ``diff``,
+  ``attribution``, ``slo``, or ``history``.
 """
 from .counters import (
     ATT_FIELDS,
@@ -63,7 +67,7 @@ from .report import (
 )
 from .slo import SLOReport, SLOResult, evaluate_slo, parse_slo
 from .spans import RequestSpan, SpanEvent, SpanTracker, tick_breakdown
-from .trace import TraceRecorder, validate_trace
+from .trace import SPAN_NAMES, TraceRecorder, span, validate_trace
 
 __all__ = [
     "ATT_FIELDS",
@@ -79,6 +83,7 @@ __all__ = [
     "SLOReport",
     "SLOResult",
     "SNAPSHOT_SCHEMA",
+    "SPAN_NAMES",
     "Series",
     "SpanEvent",
     "SpanTracker",
@@ -102,6 +107,7 @@ __all__ = [
     "render_diff",
     "render_json",
     "render_text",
+    "span",
     "static_load_frames",
     "tick_breakdown",
     "validate_snapshot",

@@ -4,18 +4,28 @@ A :class:`TraceRecorder` collects events in the Chrome Trace Event Format
 (the JSON array form under a ``traceEvents`` key), which both
 ``chrome://tracing`` and https://ui.perfetto.dev load directly:
 
-* ``complete(name, start_us, dur_us)`` / ``span(name)`` — duration slices
-  (``ph: "X"``): fabric ticks, decode ticks, bench modules;
+* ``complete(name, start_us, dur_us)`` — duration slices (``ph: "X"``):
+  fabric ticks, bench modules;
 * ``instant(name)`` — point events (``ph: "i"``): per-stream chunk
   arrivals, recompiles, deliveries (with the router ``arrive_step`` — the
   in-tick scan-step timeline — in ``args``);
 * ``counter(name, values)`` — counter tracks (``ph: "C"``): live scan
   steps per tick, queue depths, occupancy.
 
-Timestamps are microseconds since the recorder was created
+The recorder's timestamps are microseconds since it was created
 (``time.perf_counter`` based — monotonic, sub-tick resolution).  Tracks
 are named via pid/tid metadata events (``process_name``/``thread_name``),
 so fabric ranks and serve shards render as separate rows.
+
+:func:`span` is the program's one span primitive.  It always enters a
+``jax.profiler.TraceAnnotation``, so while a ``jax.profiler`` trace is
+being captured the span lands on the trace's host plane under its plain
+name, on the same clock as the device's operations, with its keyword
+arguments as event stats; with no capture running it costs about a
+microsecond.  :data:`SPAN_NAMES` lists every name the program gives a
+span, and :func:`span` takes no other, so a reader of the trace can tell
+program spans from everything else on the host plane.  Given a recorder, the same with-block also writes the
+recorder's ``ph: "X"`` slice, so the span is timed once for both.
 
 :func:`validate_trace` is the CI schema gate: it checks a loaded trace
 is a well-formed Chrome-trace event stream (list shape, required keys,
@@ -28,10 +38,22 @@ import json
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 #: event phases this recorder emits (validate_trace accepts the superset
 #: chrome://tracing documents, so hand-written traces can use more)
 PH_COMPLETE, PH_INSTANT, PH_COUNTER, PH_META = "X", "i", "C", "M"
 KNOWN_PHASES = frozenset("BEXiICMPSTFsftNODabe()")
+
+
+#: every span the serve path opens (README "Observability"); ``span``
+#: refuses any other name
+SPAN_NAMES = frozenset({
+    "serve.des", "des.plan", "des.gather", "des.split", "serve.ser",
+    "batcher.init", "batcher.admit", "batcher.dispatch", "batcher.finish",
+    "batcher.sync", "serve.tick", "stream.pump", "stream.flush",
+    "fabric.exchange", "fabric.complete",
+})
 
 
 class TraceRecorder:
@@ -88,12 +110,6 @@ class TraceRecorder:
         ev["dur"] = max(0.0, float(dur_us))
         self.events.append(ev)
 
-    def span(self, name: str, cat: str = "obs", pid: int = 0, tid: int = 0,
-             args: Optional[dict] = None) -> "_Span":
-        """``with trace.span("serve.tick"):`` — a complete event whose
-        duration is the with-block's wall time."""
-        return _Span(self, name, cat, pid, tid, args)
-
     def counter(self, name: str, values: Dict[str, float], cat: str = "obs",
                 pid: int = 0, ts: Optional[float] = None) -> None:
         self.events.append(
@@ -116,20 +132,40 @@ class TraceRecorder:
             f.write("\n")
 
 
+def span(name: str, recorder: Optional[TraceRecorder] = None,
+         **args) -> "_Span":
+    """``with span("serve.des", wires=n):`` — a host span on the profiler's
+    clock (see the module docstring).  With a ``recorder`` the with-block
+    is also one ``ph: "X"`` slice there, in the category named by the
+    span's first dotted part (``serve.tick`` -> ``serve``); the block may
+    add to ``.args`` for the slice, for values known only at its end.
+    ``name`` must be one of :data:`SPAN_NAMES`."""
+    if name not in SPAN_NAMES:
+        raise ValueError(f"span {name!r} is not in SPAN_NAMES")
+    return _Span(name, recorder, args)
+
+
 class _Span:
-    def __init__(self, rec: TraceRecorder, name: str, cat: str, pid: int,
-                 tid: int, args: Optional[dict]):
-        self.rec, self.name, self.cat = rec, name, cat
-        self.pid, self.tid, self.args = pid, tid, args
+    __slots__ = ("name", "recorder", "args", "_ann", "_t0")
+
+    def __init__(self, name: str, recorder: Optional[TraceRecorder],
+                 args: dict):
+        self.name, self.recorder, self.args = name, recorder, args
 
     def __enter__(self) -> "_Span":
-        self._start = self.rec.now_us()
+        self._ann = TraceAnnotation(self.name, **self.args)
+        self._ann.__enter__()
+        if self.recorder is not None:
+            self._t0 = self.recorder.now_us()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.rec.complete(self.name, self._start,
-                          self.rec.now_us() - self._start, cat=self.cat,
-                          pid=self.pid, tid=self.tid, args=self.args)
+        rec = self.recorder
+        if rec is not None:
+            rec.complete(self.name, self._t0, rec.now_us() - self._t0,
+                         cat=self.name.partition(".")[0],
+                         args=dict(self.args) or None)
+        self._ann.__exit__(*exc)
 
 
 def validate_trace(obj) -> List[str]:

@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.base import ModelConfig
+from ..obs.trace import span
 
 # NB: launch.steps / models are imported lazily inside ContinuousBatcher —
 # models itself pulls in repro.runtime (actshard), so a module-level import
@@ -126,23 +127,24 @@ class ContinuousBatcher:
         #: surfaced per tick in :attr:`tick_logprobs` (the greedy pick is
         #: unchanged — token output is byte-identical either way)
         self.logprobs = logprobs
-        self.prefill_step, self.decode_step = cached_serve_steps(
-            cfg, cache_len=sched.cache_len, logprobs=logprobs
-        )
-        # The slot cache must be row-compatible with what prefill emits —
-        # families can grow it beyond prompt_cap + max_new (e.g. vlm KV
-        # includes the vision prefix) — so allocate it from prefill's
-        # eval_shape with the batch dim widened to `slots`.  The cache is
-        # the last output either way (tok[, lp], cache).
-        out_spec = jax.eval_shape(
-            self.prefill_step, params, self._batch_specs(sched.admit_width)
-        )
-        cache_spec = out_spec[-1]
-        self.cache = jax.tree.map(
-            lambda s: jnp.zeros((sched.slots,) + s.shape[1:], s.dtype), cache_spec
-        )
-        self.cur_tok = jnp.zeros((sched.slots, 1), jnp.int32)
-        self.cur_lp = jnp.zeros((sched.slots, 1), jnp.float32)
+        with span("batcher.init", slots=sched.slots):
+            self.prefill_step, self.decode_step = cached_serve_steps(
+                cfg, cache_len=sched.cache_len, logprobs=logprobs
+            )
+            # The slot cache must be row-compatible with what prefill emits
+            # — families can grow it beyond prompt_cap + max_new (e.g. vlm
+            # KV includes the vision prefix) — so allocate it from
+            # prefill's eval_shape with the batch dim widened to `slots`.
+            # The cache is the last output either way (tok[, lp], cache).
+            out_spec = jax.eval_shape(
+                self.prefill_step, params, self._batch_specs(sched.admit_width)
+            )
+            cache_spec = out_spec[-1]
+            self.cache = jax.tree.map(
+                lambda s: jnp.zeros((sched.slots,) + s.shape[1:], s.dtype), cache_spec
+            )
+            self.cur_tok = jnp.zeros((sched.slots, 1), jnp.int32)
+            self.cur_lp = jnp.zeros((sched.slots, 1), jnp.float32)
         #: (seq_id, position) -> logprob of every emission of the last tick
         #: (only filled when ``logprobs=True``); the step_finish triple API
         #: is unchanged so logprob-free callers never pay for it
@@ -191,43 +193,46 @@ class ContinuousBatcher:
             return
         A = self.sched.admit_width
         take = min(len(free), A, len(self.pending))
-        seqs = [self.pending.popleft() for _ in range(take)]
-        S = self.sched.prompt_cap
-        toks = np.zeros((A, S), np.int32)
-        for j, seq in enumerate(seqs):
-            toks[j, : min(len(seq.tokens), S)] = seq.tokens[:S]
-        batch = dict(self._extra_inputs)
-        batch["tokens"] = jnp.asarray(toks)
-        if self.logprobs:
-            next_tok, next_lp, new_cache = self.prefill_step(self.params, batch)
-        else:
-            next_tok, new_cache = self.prefill_step(self.params, batch)
-            next_lp = None
-        # unused admit rows -> OOB slot id, dropped by the scatter
-        slot_ids = np.full(A, self.sched.slots, np.int32)
-        slot_ids[:take] = free[:take]
-        self.cache, self.cur_tok = _scatter_rows(
-            self.cache, self.cur_tok, new_cache, next_tok, jnp.asarray(slot_ids)
-        )
-        first = np.asarray(next_tok)[:take, 0]
-        if next_lp is not None:
-            self.cur_lp = _scatter_vec(
-                self.cur_lp, next_lp, jnp.asarray(slot_ids)
+        with span("batcher.admit", admitted=take):
+            seqs = [self.pending.popleft() for _ in range(take)]
+            S = self.sched.prompt_cap
+            toks = np.zeros((A, S), np.int32)
+            for j, seq in enumerate(seqs):
+                toks[j, : min(len(seq.tokens), S)] = seq.tokens[:S]
+            batch = dict(self._extra_inputs)
+            batch["tokens"] = jnp.asarray(toks)
+            if self.logprobs:
+                next_tok, next_lp, new_cache = self.prefill_step(self.params, batch)
+            else:
+                next_tok, new_cache = self.prefill_step(self.params, batch)
+                next_lp = None
+            # unused admit rows -> OOB slot id, dropped by the scatter
+            slot_ids = np.full(A, self.sched.slots, np.int32)
+            slot_ids[:take] = free[:take]
+            self.cache, self.cur_tok = _scatter_rows(
+                self.cache, self.cur_tok, new_cache, next_tok, jnp.asarray(slot_ids)
             )
-            first_lp = np.asarray(next_lp)[:take, 0]
-        for j, seq in enumerate(seqs):
-            seq.out.append(int(first[j]))
-            seq.remaining = self.sched.max_new - 1
-            self.active[free[j]] = seq
-            self._tick_emit.append((seq.seq_id, 0, int(first[j])))
             if next_lp is not None:
-                self.tick_logprobs[(seq.seq_id, 0)] = float(first_lp[j])
-            if self.spans is not None and seq.seq_id in self.span_of:
-                self.spans.event(self.span_of[seq.seq_id], "batcher.admit",
-                                 slot=free[j])
-        if self.metrics is not None:
-            self.metrics.counter("batcher.admitted").add(take)
-        self._evict()
+                self.cur_lp = _scatter_vec(
+                    self.cur_lp, next_lp, jnp.asarray(slot_ids)
+                )
+            with span("batcher.sync"):
+                first = np.asarray(next_tok)[:take, 0]
+                if next_lp is not None:
+                    first_lp = np.asarray(next_lp)[:take, 0]
+            for j, seq in enumerate(seqs):
+                seq.out.append(int(first[j]))
+                seq.remaining = self.sched.max_new - 1
+                self.active[free[j]] = seq
+                self._tick_emit.append((seq.seq_id, 0, int(first[j])))
+                if next_lp is not None:
+                    self.tick_logprobs[(seq.seq_id, 0)] = float(first_lp[j])
+                if self.spans is not None and seq.seq_id in self.span_of:
+                    self.spans.event(self.span_of[seq.seq_id], "batcher.admit",
+                                     slot=free[j])
+            if self.metrics is not None:
+                self.metrics.counter("batcher.admitted").add(take)
+            self._evict()
 
     def _evict(self) -> None:
         evicted = 0
@@ -259,14 +264,15 @@ class ContinuousBatcher:
         if self.n_active == 0:
             self._stepped = False
             return False
-        if self.logprobs:
-            self.cur_tok, self.cur_lp, self.cache = self.decode_step(
-                self.params, self.cache, self.cur_tok
-            )
-        else:
-            self.cur_tok, self.cache = self.decode_step(
-                self.params, self.cache, self.cur_tok
-            )
+        with span("batcher.dispatch", live=self.n_active):
+            if self.logprobs:
+                self.cur_tok, self.cur_lp, self.cache = self.decode_step(
+                    self.params, self.cache, self.cur_tok
+                )
+            else:
+                self.cur_tok, self.cache = self.decode_step(
+                    self.params, self.cache, self.cur_tok
+                )
         self.steps_run += 1
         self._stepped = True
         if self.metrics is not None:
@@ -284,17 +290,19 @@ class ContinuousBatcher:
         if not self._stepped:
             return emitted
         self._stepped = False
-        toks = np.asarray(self.cur_tok)[:, 0]  # one host sync per tick
-        lps = np.asarray(self.cur_lp)[:, 0] if self.logprobs else None
-        for i, seq in enumerate(self.active):
-            if seq is not None:
-                seq.out.append(int(toks[i]))
-                seq.remaining -= 1
-                pos = len(seq.out) - 1
-                emitted.append((seq.seq_id, pos, int(toks[i])))
-                if lps is not None:
-                    self.tick_logprobs[(seq.seq_id, pos)] = float(lps[i])
-        self._evict()
+        with span("batcher.finish"):
+            with span("batcher.sync"):  # one host sync per tick
+                toks = np.asarray(self.cur_tok)[:, 0]
+                lps = np.asarray(self.cur_lp)[:, 0] if self.logprobs else None
+            for i, seq in enumerate(self.active):
+                if seq is not None:
+                    seq.out.append(int(toks[i]))
+                    seq.remaining -= 1
+                    pos = len(seq.out) - 1
+                    emitted.append((seq.seq_id, pos, int(toks[i])))
+                    if lps is not None:
+                        self.tick_logprobs[(seq.seq_id, pos)] = float(lps[i])
+            self._evict()
         return emitted
 
     def step(self) -> None:

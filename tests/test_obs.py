@@ -20,6 +20,8 @@ Runs on the 8 simulated host devices from ``conftest.py``.
 """
 import dataclasses
 import json
+import pathlib
+import sys
 
 import jax
 import numpy as np
@@ -29,8 +31,10 @@ from repro.fabric import Fabric, FabricConfig
 from repro.obs import (
     ClassWindows,
     MetricsRegistry,
+    SPAN_NAMES,
     TraceRecorder,
     format_key,
+    span,
     validate_snapshot,
     validate_trace,
     window_stats,
@@ -284,7 +288,7 @@ def test_recompile_counter_machine_readable_and_flat_after_warmup():
 def test_trace_recorder_emits_valid_chrome_trace(tmp_path):
     tr = TraceRecorder()
     tr.name_track(0, "fabric", tid=1, thread="ticks")
-    with tr.span("tick", cat="fabric", args={"frames": 4}):
+    with span("serve.tick", tr, frames=4):
         tr.instant("chunk.arrive", pid=1, args={"stream": 2})
     tr.counter("inflight", {"frames": 3.0})
     obj = tr.to_json()
@@ -292,14 +296,50 @@ def test_trace_recorder_emits_valid_chrome_trace(tmp_path):
     assert obj["displayTimeUnit"] == "ms"
     phases = {e["ph"] for e in obj["traceEvents"]}
     assert {"X", "i", "C", "M"} <= phases
-    span = next(e for e in obj["traceEvents"] if e["ph"] == "X")
-    assert span["dur"] >= 0 and span["args"]["frames"] == 4
+    ev = next(e for e in obj["traceEvents"] if e["ph"] == "X")
+    assert ev["dur"] >= 0 and ev["args"]["frames"] == 4
     out = tmp_path / "t.json"
     tr.save(out)
     assert validate_trace(json.loads(out.read_text())) == []
     # bare-list form (what some tools emit) validates too
     assert validate_trace(obj["traceEvents"]) == []
     assert validate_trace({"nope": 1})  # and garbage is rejected
+
+
+def test_span_writes_recorder_slice_and_profiler_event(tmp_path):
+    """One with-block, timed once: the recorder's slice (name, category from
+    the first dotted part, args including those added inside the block) and
+    the profiler trace's host event under the same plain name."""
+    root = str(pathlib.Path(__file__).parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.chip import trace_reduce
+
+    tr = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with span("serve.tick", tr, tick=3) as sp:
+            with span("stream.flush"):
+                pass
+            sp.args["tokens_arrived"] = 5
+    finally:
+        jax.profiler.stop_trace()
+    (ev,) = tr.events
+    assert (ev["name"], ev["ph"], ev["cat"]) == ("serve.tick", "X", "serve")
+    assert ev["args"] == {"tick": 3, "tokens_arrived": 5} and ev["dur"] >= 0
+    assert validate_trace(tr.to_json()) == []
+    host = trace_reduce.load(trace_reduce.find_xplane(str(tmp_path))).host
+    names = list(host.names)
+    tick, flush = names.index("serve.tick"), names.index("stream.flush")
+    assert host.start[tick] <= host.start[flush] and host.end[flush] <= host.end[tick]
+    # no recorder, no profiler: a plain with-block, exceptions pass through
+    with pytest.raises(KeyError):
+        with span("serve.des", wires=2):
+            raise KeyError("x")
+    # a name outside SPAN_NAMES would be invisible to the trace's readers
+    assert {"serve.tick", "stream.flush", "serve.des"} <= SPAN_NAMES
+    with pytest.raises(ValueError, match="SPAN_NAMES"):
+        span("serve.unlisted")
 
 
 def test_obs_cli_validates_artifacts(tmp_path):
@@ -310,7 +350,7 @@ def test_obs_cli_validates_artifacts(tmp_path):
     mfile = tmp_path / "m.json"
     mfile.write_text(m.to_json())
     tr = TraceRecorder()
-    with tr.span("s"):
+    with span("serve.tick", tr):
         pass
     tfile = tmp_path / "t.json"
     tr.save(tfile)
@@ -367,7 +407,7 @@ def test_streaming_serve_telemetry_is_byte_invisible(serve_setup):
                      "batcher.admitted", "batcher.occupancy",
                      "batcher.steps", "stream.reader.chunks",
                      "stream.reader.tokens", "fabric.frames.delivered",
-                     "fabric.ticks"):
+                     "fabric.ticks", "serve.shard.device"):
         assert required in names, required
     flat = metrics.flat()
     assert flat["serve.tokens"] > 0
