@@ -81,6 +81,36 @@ def attn_forward(
     return out, (k, v)
 
 
+def write_decode_rows(cache: jnp.ndarray, rows: jnp.ndarray,
+                      slot: jnp.ndarray) -> jnp.ndarray:
+    """cache[b, slot[b]] = rows[b] for every b of a (B,T,K,D) cache; a row
+    with slot >= T is dropped.
+
+    The write depends on the head dim because the TPU stores the cache in
+    the layout that fills its 128-lane tiles.  Where D is a multiple of 128
+    the cache is stored D-minor and the batch-indexed scatter writes it in
+    place.  Otherwise (D = 80) T takes the lanes, the scatter is emitted only
+    D-minor, and the compiler relayouts the whole cache around it: two
+    full-cache copies per array per layer, every step.  There each row is
+    written by its own dynamic_update_slice, which stays in the stored
+    layout.  Its batch index is static: a vmapped per-row DUS would defeat
+    SPMD batch partitioning of the cache and replicate it.
+    """
+    B, T = cache.shape[:2]
+    if cache.shape[-1] % 128 == 0:
+        b_idx = jnp.arange(B, dtype=jnp.int32)
+        return cache.at[b_idx, slot].set(rows, mode="drop")
+    # dynamic_update_slice clamps its start to T - 1 where the scatter drops:
+    # such a row writes back what row T - 1 held.  A static slice reads it;
+    # a dynamic read at a static b would all-gather a batch-sharded cache.
+    keep = (slot >= T).reshape(B, 1, 1, 1)
+    start = jnp.minimum(slot, T - 1)
+    rows = jnp.where(keep, cache[:, T - 1:], rows.astype(cache.dtype)[:, None])
+    for b in range(B):
+        cache = jax.lax.dynamic_update_slice(cache, rows[b:b + 1], (b, start[b], 0, 0))
+    return cache
+
+
 def attn_decode(
     p: Dict,
     x: jnp.ndarray,  # (B,1,d)
@@ -99,11 +129,8 @@ def attn_decode(
         q = q.reshape(B, 1, cfg.n_kv, cfg.n_heads // cfg.n_kv, cfg.hd)
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
     slot = pos % T if window is not None else pos  # ring buffer for SWA
-    # batch-indexed scatter (NOT vmap'd dynamic_update_slice: the per-row
-    # DUS defeats SPMD batch partitioning of the cache and replicates it)
-    b_idx = jnp.arange(B, dtype=jnp.int32)
-    kc = cache["k"].at[b_idx, slot].set(k[:, 0], mode="drop")
-    vc = cache["v"].at[b_idx, slot].set(v[:, 0], mode="drop")
+    kc = write_decode_rows(cache["k"], k[:, 0], slot)
+    vc = write_decode_rows(cache["v"], v[:, 0], slot)
     kv_len = jnp.minimum(pos + 1, T) if window is not None else pos + 1
     out = decode_attention(q, kc, vc, kv_len, logit_cap=cfg.attn_softcap)
     out = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"]

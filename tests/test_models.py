@@ -122,3 +122,51 @@ def test_moe_capacity_drops_and_balance():
     cfg2 = dataclasses.replace(cfg, capacity_factor=8.0)
     y2, aux2 = moe_ffn(p, x, cfg2)
     assert float(aux2["moe_dropped"]) == 0.0
+
+
+def _scatter_rows(cache, rows, slot):
+    b_idx = jnp.arange(cache.shape[0], dtype=jnp.int32)
+    return cache.at[b_idx, slot].set(rows, mode="drop")
+
+
+@pytest.mark.parametrize("hd", [80, 128])
+@pytest.mark.parametrize("case", ["in_range", "last_row", "past_end", "ring"])
+def test_decode_row_write_matches_scatter(hd, case, monkeypatch):
+    """The per-row cache write (head dim 80) and the batched scatter (128)
+    give the batched scatter's cache bit for bit, and attn_decode's output
+    does not change; a row past the cache's end leaves the cache as it was."""
+    from repro.models import attention
+
+    B, T = 4, 16
+    pos = {
+        "in_range": [3, 7, 0, 5],
+        "last_row": [T - 1, 2, T - 1, 9],
+        "past_end": [T, 4, T + 5, T - 1],
+        "ring": [T + 3, 2 * T - 1, 5, 3 * T],
+    }[case]
+    pos = jnp.asarray(pos, jnp.int32)
+    window = T if case == "ring" else None
+    cfg = dataclasses.replace(smoke_config(get_config("stablelm-3b")),
+                              head_dim=hd, dtype="bfloat16")
+    params = init_params(cfg, KEY)
+    p = params["layers"][0]["attn"]
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    shape = (B, T, cfg.n_kv, hd)
+    cache = {"k": jax.random.normal(ks[0], shape, jnp.bfloat16),
+             "v": jax.random.normal(ks[1], shape, jnp.bfloat16)}
+    x = jax.random.normal(ks[2], (B, 1, cfg.d_model), jnp.bfloat16)
+
+    slot = pos % T if window is not None else pos
+    rows = jax.random.normal(ks[0], (B, cfg.n_kv, hd), jnp.bfloat16)
+    got = attention.write_decode_rows(cache["k"], rows, slot)
+    want = _scatter_rows(cache["k"], rows, slot)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for b in np.flatnonzero(np.asarray(slot) >= T):
+        np.testing.assert_array_equal(np.asarray(got[b]), np.asarray(cache["k"][b]))
+
+    out, new = attention.attn_decode(p, x, cfg, cache, pos, window=window)
+    monkeypatch.setattr(attention, "write_decode_rows", _scatter_rows)
+    out_ref, new_ref = attention.attn_decode(p, x, cfg, cache, pos, window=window)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(out_ref))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(new[name]), np.asarray(new_ref[name]))

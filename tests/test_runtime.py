@@ -1,6 +1,7 @@
 """Distribution runtime: shardings resolve, framed channels, compression,
 pipeline, end-to-end sharded train step on a small mesh."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -173,3 +174,14 @@ def test_serve_step_sharded(mesh):
     toks = jnp.ones((8, 1), jnp.int32)
     nt, c2 = jitted(params, cache, toks)
     assert nt.shape == (8, 1)
+
+
+def test_serve_step_keeps_cache_batch_sharded(mesh):
+    # the smoke head dim (32) takes the per-row cache write: its lowering must
+    # not all-gather a batch-sharded cache array
+    from repro.launch.dryrun import lower_cell
+    from repro.configs.base import ShapeConfig
+    cfg = dataclasses.replace(smoke_config(get_config("yi-6b")), n_layers=2)
+    lowered, _, _ = lower_cell(cfg, ShapeConfig("d", 64, 8, "decode"), mesh)
+    txt = lowered.compile().as_text()
+    assert re.findall(r"= \w+\[8,64,\d+,%d\]\S* all-gather\(" % cfg.hd, txt) == []

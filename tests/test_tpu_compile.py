@@ -6,7 +6,9 @@ layouts, VMEM).  Sizes are serving sizes: a few thousand wire words and
 1-2k rows.  Every test asserts that the compiled program holds the kernel
 (``tpu_custom_call``) rather than an interpreted or XLA fallback.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,3 +98,84 @@ def test_unpack_frames_batch_compiles(one_chip):
         one_chip, ((1024, HDR_WORDS + 64), jnp.uint32),
     )
     assert "tpu_custom_call" in txt
+
+
+def _serve_step_entry(cfg, sharding, batch, cache_len):
+    """The compiled decode step's entry computation, as the batcher jits it
+    (cache donated), for a described v5e chip."""
+    from repro.launch.steps import make_serve_step
+    from repro.models import init_cache, init_params
+
+    def spec(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
+
+    params = spec(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = spec(jax.eval_shape(lambda: init_cache(cfg, batch, cache_len)))
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=sharding)
+    step = jax.jit(make_serve_step(cfg), donate_argnums=(1,))
+    txt = step.lower(params, cache, tokens).compile().as_text()
+    entry = txt[txt.index("\nENTRY"):]
+    return entry[:entry.index("\n}")].splitlines()
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) ([\w-]+)\((.*)")
+
+
+def _layouts(type_str, shape):
+    return set(re.findall(re.escape(shape) + r"\{([\d,]+)", type_str))
+
+
+def _ops_out(lines, opcodes, shape):
+    """Instructions of ``opcodes`` whose output type holds ``shape``."""
+    hits = []
+    for line in lines:
+        m = _INSTR.match(line)
+        if m and m.group(3) in opcodes and _layouts(m.group(2), shape):
+            hits.append(line)
+    return hits
+
+
+def _relayouts(lines, shape):
+    """Copies that change the layout of an array of ``shape``.  A copy that
+    keeps the layout only moves the array between memory spaces."""
+    types, hits = {}, []
+    for line in lines:
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, ty, op, rest = m.groups()
+        types[name] = ty
+        if op in ("copy", "copy-start"):
+            src = re.match(r"%[\w.-]+", rest)
+            seen = _layouts(ty, shape) | _layouts(types.get(src and src.group(0), ""), shape)
+            if len(seen) > 1:
+                hits.append(line)
+    return hits
+
+
+def test_stablelm_decode_writes_cache_in_place(one_chip):
+    # head dim 80: the cache is stored T-minor; the decode step's row write
+    # must not relayout the whole cache around it
+    from repro.configs import get_config
+
+    cfg = dataclasses.replace(
+        get_config("stablelm-3b"), n_layers=2, norm="layernorm", norm_eps=1e-5,
+        tie_embeddings=False)
+    lines = _serve_step_entry(cfg, one_chip, batch=16, cache_len=1152)
+    full = "bf16[16,1152,32,80]"
+    assert _relayouts(lines, full) == []
+    assert _ops_out(lines, {"dynamic-update-slice"}, full)
+
+
+def test_yi_decode_keeps_batched_scatter(one_chip):
+    # head dim 128: the cache is stored D-minor and the scatter is native
+    from repro.configs import get_config
+
+    cfg = dataclasses.replace(get_config("yi-6b"), n_layers=2)
+    lines = _serve_step_entry(cfg, one_chip, batch=32, cache_len=384)
+    full = "bf16[32,384,4,128]"
+    scatters = [l for l in _ops_out(lines, {"fusion"}, full) if "/scatter" in l]
+    assert len(scatters) == 2 * cfg.n_layers  # one per K and per V
+    assert _relayouts(lines, full) == []
+    assert _ops_out(lines, {"dynamic-update-slice"}, full) == []
