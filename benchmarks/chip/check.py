@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights, wire
+from . import spec, wire
 
 
 @dataclass
@@ -104,18 +104,18 @@ def served_sequence(s: Served, pad_to: int) -> Tuple[np.ndarray, np.ndarray]:
     return seq, rows
 
 
-def logit_gaps(ref_mod, config: Dict, params, seqs: List[Served], pad_to: int,
-               control: bool = False) -> float:
+def logit_gaps(cell, params, seqs: List[Served], control: bool = False) -> float:
     """Widest gap over ``seqs``: of the served tokens, or with ``control``
-    of the tokens that the float8 control ranks first."""
-    embed, head = weights.embedding(params), weights.output_head(params)
-    layers = weights.layer_view(params)
+    of the tokens that the float8 control ranks first.  The cell's reference
+    reads the weights through its family's ``view``."""
+    ref_mod = spec.reference_module(cell.config)
+    view = cell.family.view(params)
     worst = 0.0
     for s in seqs:
-        seq, rows = served_sequence(s, pad_to)
-        ref = ref_mod.logits(config, embed, layers, head, seq, rows)
+        seq, rows = served_sequence(s, cell.mix["pad_to"])
+        ref = ref_mod.logits(cell.config, view, seq, rows)
         if control:
-            low = ref_mod.logits(config, embed, layers, head, seq, rows, quant=True)
+            low = ref_mod.logits(cell.config, view, seq, rows, quant=True)
             tok = jnp.argmax(low, -1).astype(jnp.int32)
         else:
             tok = jnp.asarray(s.tokens, jnp.int32)

@@ -28,11 +28,12 @@ def readings(cell, seeds, control_seeds, require_chip=True, cfg=None):
     if require_chip:
         harness.use_compile_cache()
     cfg = cfg or spec.program_config(cell.config)
-    mix, ref = cell.mix, spec.reference_module(cell.config)
+    mix = cell.mix
     entry_mod = spec.entry_module(mix["entry"])
     for seed in seeds:
         t0 = time.perf_counter()
-        params = weights.make_params(cfg, cell.config["initializer_range"], seed)
+        params = weights.make_params(cfg, cell.config["initializer_range"], seed,
+                                     cell.family.init_kind)
         entry = entry_mod.Entry(params, cfg, dict(mix))
         burst = traffic.Generator(mix, cfg.vocab, seed).burst(0)
         res = entry.call(burst.wires)
@@ -43,10 +44,9 @@ def readings(cell, seeds, control_seeds, require_chip=True, cfg=None):
         gc.collect()
         seqs = check.sample(served, mix["check_sequences"], seed)
         out = {"seed": seed, "bad_wires": bad, "sequences": len(seqs),
-               "program": check.logit_gaps(ref, cell.config, params, seqs, mix["pad_to"])}
+               "program": check.logit_gaps(cell, params, seqs)}
         if seed in control_seeds:
-            out["control"] = check.logit_gaps(ref, cell.config, params, seqs,
-                                              mix["pad_to"], control=True)
+            out["control"] = check.logit_gaps(cell, params, seqs, control=True)
         out["seconds"] = time.perf_counter() - t0
         del params
         gc.collect()

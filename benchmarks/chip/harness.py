@@ -67,7 +67,12 @@ class Run:
         return self.t1 - self.t0
 
     @property
-    def sizes(self) -> spec.Sizes:
+    def family(self):
+        """The cell's family module: its counts take ``sizes``."""
+        return self.cell.family
+
+    @property
+    def sizes(self):
         return self.cell.sizes
 
     def sequences(self):
@@ -182,7 +187,8 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: fl
     mix = cell.mix
     counter = _CompileCounter()
 
-    params = weights.make_params(cfg, cell.config["initializer_range"], seed)
+    params = weights.make_params(cfg, cell.config["initializer_range"], seed,
+                                 cell.family.init_kind)
     log(f"[bench] weights made at {time.perf_counter() - t_start:.3f} s")
     entry_mod = spec.entry_module(mix["entry"])
     entry = entry_mod.Entry(params, cfg, {k: v for k, v in mix.items()})
@@ -237,9 +243,7 @@ def measure(cell: spec.Cell, seed: int, seconds: float, trace: bool, t_start: fl
     gc.collect()
     t_ref = time.perf_counter()
     seqs = check.sample(served, mix["check_sequences"], seed)
-    ref_mod = spec.reference_module(cell.config)
-    gap = (check.logit_gaps(ref_mod, cell.config, params, seqs, mix["pad_to"])
-           if seqs else None)
+    gap = check.logit_gaps(cell, params, seqs) if seqs else None
     verdict = check.judge(cell.limits, wires_bad, stream_bad, gap, run.failed)
     log(f"[bench] reference check of {len(seqs)} sequences: "
         f"{time.perf_counter() - t_ref:.3f} s")

@@ -4,7 +4,8 @@ Straight from the published equations (HF ``LlamaForCausalLM`` and
 ``StableLmForCausalLM``), in ``jax.numpy`` and float32 with every matrix
 product at ``Precision.HIGHEST``, one sequence and one layer at a time, with
 no cache, no blocking and no kernels.  It imports nothing of the program:
-the weights come from ``weights.py`` and the sizes from the config file.
+the weights come from ``weights.py`` through ``families/dense_decoder.py``
+``view``, and the sizes from the config file.
 
 Per layer, pre-norm:
 
@@ -33,7 +34,7 @@ the bfloat16 the configurations state.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -130,13 +131,14 @@ def config_key(c: Dict):
     return tuple((k, c[k]) for k in _KEYS if k in c)
 
 
-def logits(config: Dict, embed, layers: List[Dict], head, tokens: np.ndarray,
-           rows: np.ndarray, quant: bool = False) -> jax.Array:
+def logits(config: Dict, view: Dict, tokens: np.ndarray, rows: np.ndarray,
+           quant: bool = False) -> jax.Array:
     """float32 logits ``(len(rows), V)`` at positions ``rows`` of one sequence
-    ``tokens (T,)``, computed layer by layer."""
+    ``tokens (T,)``, computed layer by layer from ``view`` (the family's:
+    ``embed``, ``layers``, ``head``)."""
     ckey = config_key(config)
     with jax.default_matmul_precision("highest"):
-        x = _embed(embed, jnp.asarray(tokens, jnp.int32), ckey, quant)
-        for w in layers:
+        x = _embed(view["embed"], jnp.asarray(tokens, jnp.int32), ckey, quant)
+        for w in view["layers"]:
             x = _layer(x, w, ckey, quant)
-        return _head(x, head, jnp.asarray(rows, jnp.int32), ckey, quant)
+        return _head(x, view["head"], jnp.asarray(rows, jnp.int32), ckey, quant)

@@ -30,6 +30,8 @@ def test_cell_found_by_name(name):
     assert "logit_gap" in cell.limits
     assert "setup_s" in cell.e2e and len(cell.e2e) >= 2 and cell.per_layer
     assert spec.reference_module(cell.config).logits
+    assert cell.family is spec.family_module(cell.config)
+    assert cell.sizes == cell.family.sizes(cell.config)
 
 
 @pytest.mark.parametrize("name", METRICS)

@@ -23,25 +23,23 @@ def test_reference_matches_program_forward(name):
 
     cell, cfg = tiny_cell(name)
     cfg = dataclasses.replace(cfg, dtype="float32", remat=False)
-    params = weights.make_params(cfg, 0.02, 5)
+    params = weights.make_params(cfg, 0.02, 5, cell.family.init_kind)
     toks = np.random.default_rng(1).integers(1, cfg.vocab, 24)
     with jax.default_matmul_precision("highest"):
         prog, _, _ = forward(params, cfg, {"tokens": jnp.asarray(toks[None], jnp.int32)})
     from benchmarks.chip.references import dense_decoder as ref
 
-    got = ref.logits(cell.config, weights.embedding(params), weights.layer_view(params),
-                     weights.output_head(params), toks, np.arange(24))
+    got = ref.logits(cell.config, cell.family.view(params), toks, np.arange(24))
     np.testing.assert_allclose(np.asarray(got), np.asarray(prog[0]), rtol=2e-4, atol=2e-4)
 
 
 def test_control_departs_from_reference():
     cell, cfg = tiny_cell("yi6b.chat")
-    params = weights.make_params(cfg, 0.02, 5)
+    params = weights.make_params(cfg, 0.02, 5, cell.family.init_kind)
     from benchmarks.chip.references import dense_decoder as ref
 
     toks = np.random.default_rng(2).integers(1, cfg.vocab, 24)
-    args = (cell.config, weights.embedding(params), weights.layer_view(params),
-            weights.output_head(params), toks, np.arange(24))
+    args = (cell.config, cell.family.view(params), toks, np.arange(24))
     hi, lo = np.asarray(ref.logits(*args)), np.asarray(ref.logits(*args, quant=True))
     err = np.abs(hi - lo).max() / hi.std()
     assert 1e-3 < err < 0.5
