@@ -41,9 +41,18 @@ class ModelConfig:
     moe_offset: int = 0
     moe_dff: Optional[int] = None  # expert hidden dim (default d_ff)
     capacity_factor: float = 1.25
+    # experts held here: ids [moe_held_first, moe_held_first + moe_held) of the
+    # moe_experts the router scores (0 = all); the serving layer computes only
+    # their share of each token's output (model-configs §4, expert parallel)
+    moe_held: int = 0
+    moe_held_first: int = 0
+    moe_shared_dff: int = 0  # width of a shared SwiGLU expert beside the routed ones
 
     # --- layer pattern (hybrid models) ---
     layer_pattern: str = "attn"  # "attn" | "jamba" (attn every 8th) | "xlstm"
+    # a published per-layer list ("mamba" | "attention"), which takes the
+    # place of layer_pattern; a config of fewer layers keeps its first n_layers
+    layer_types: Optional[Tuple[str, ...]] = None
 
     # --- SSM (mamba / xlstm) dims ---
     ssm_state: int = 16
@@ -64,6 +73,10 @@ class ModelConfig:
     norm: str = "rmsnorm"  # rmsnorm | layernorm
     norm_eps: float = 1e-6
     embed_scale: bool = False  # multiply embeddings by sqrt(d) (gemma)
+    embed_mult: float = 1.0  # embeddings times this (granite embedding_multiplier)
+    residual_mult: float = 1.0  # every residual branch times this (granite)
+    logits_div: float = 1.0  # logits divided by this (granite logits_scaling)
+    attn_scale: Optional[float] = None  # score scale; None is 1/sqrt(head dim)
     sandwich_norm: bool = False  # post-sublayer norms (gemma2)
     tie_embeddings: bool = True
     dtype: str = "bfloat16"
@@ -79,6 +92,17 @@ class ModelConfig:
     # --- provenance ---
     source: str = ""
     verified: str = "unverified"
+
+    def __post_init__(self) -> None:
+        if self.layer_types is not None:
+            if len(self.layer_types) < self.n_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} layer_types "
+                                 f"for {self.n_layers} layers")
+            object.__setattr__(self, "layer_types", tuple(self.layer_types[: self.n_layers]))
+        if self.moe_held and self.moe_held_first + self.moe_held > self.moe_experts:
+            raise ValueError(f"{self.name}: experts {self.moe_held_first}.."
+                             f"{self.moe_held_first + self.moe_held - 1} held of "
+                             f"{self.moe_experts}")
 
     @property
     def hd(self) -> int:
@@ -96,6 +120,12 @@ class ModelConfig:
 
     def layer_kinds(self) -> Tuple[str, ...]:
         """Sequence kind per layer: attn | mamba | mlstm | slstm."""
+        if self.layer_types is not None:
+            kinds = {"attention": "attn", "mamba": "mamba"}
+            bad = sorted(set(self.layer_types) - set(kinds))
+            if bad:
+                raise ValueError(f"unknown layer_types {bad}")
+            return tuple(kinds[t] for t in self.layer_types)
         if self.layer_pattern == "attn":
             return ("attn",) * self.n_layers
         if self.layer_pattern == "jamba":
@@ -138,13 +168,17 @@ class ModelConfig:
         glu = 3 if self.act in ("swiglu", "geglu") else 2
         dense_ffn = glu * d * self.d_ff
         moe_dff = self.moe_dff or self.d_ff
-        moe_ffn = self.moe_experts * glu * d * moe_dff + d * self.moe_experts
+        shared = glu * d * self.moe_shared_dff
+        moe_ffn = self.moe_experts * glu * d * moe_dff + d * self.moe_experts + shared
         d_in = self.ssm_expand * d
+        conv_dim = d_in + 2 * self.ssm_state  # x, B, C convolved together
+        nh = d_in // self.ssm_head
         mamba = (
-            2 * d * d_in  # in/out proj (x and gate)
-            + d_in * self.ssm_conv
-            + d_in * (2 * self.ssm_state + d_in // self.ssm_head)  # B,C,dt heads
-            + d_in
+            d * (2 * d_in + 2 * self.ssm_state + nh)  # in_proj: z, x, B, C, dt
+            + d_in * d  # out_proj
+            + conv_dim * (self.ssm_conv + 1)  # conv weight and bias
+            + 3 * nh  # A_log, D, dt_bias
+            + d_in  # gated norm
         )
         # q,k,v + output gate (d->d_in each) + out_proj + i/f gate heads
         mlstm = 5 * d * d_in + 2 * d * self.n_heads + 3 * d_in
@@ -160,7 +194,7 @@ class ModelConfig:
                 active += dense_ffn
             elif fk == "moe":
                 total += moe_ffn
-                active += d * self.moe_experts + self.moe_topk * glu * d * moe_dff
+                active += d * self.moe_experts + self.moe_topk * glu * d * moe_dff + shared
         if self.family == "encdec":
             enc = self.enc_layers * (attn + dense_ffn)
             cross = self.n_layers * attn
@@ -221,6 +255,7 @@ def _ensure_loaded() -> None:
     from . import (  # noqa: F401
         gemma2_27b,
         granite_34b,
+        granite_4_0_h_small,
         yi_6b,
         stablelm_3b,
         whisper_tiny,
@@ -248,9 +283,14 @@ def supports_shape(cfg: ModelConfig, shape: ShapeConfig) -> Tuple[bool, str]:
 
 def smoke_config(cfg: ModelConfig) -> ModelConfig:
     """Reduced same-family config for CPU smoke tests."""
+    n_layers = min(cfg.n_layers, 4 if cfg.layer_pattern == "attn" else 8)
+    if cfg.layer_types is not None and "attention" in cfg.layer_types:
+        # through the first attention layer, so that both kinds are present
+        n_layers = cfg.layer_types.index("attention") + 1
+    moe_experts = min(cfg.moe_experts, 4)
     return replace(
         cfg,
-        n_layers=min(cfg.n_layers, 4 if cfg.layer_pattern == "attn" else 8),
+        n_layers=n_layers,
         d_model=128,
         n_heads=4,
         n_kv=min(cfg.n_kv, 4) if cfg.n_kv > 1 else 1,
@@ -258,7 +298,11 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
         d_ff=0 if cfg.d_ff == 0 else 256,
         moe_dff=None,
         vocab=512,
-        moe_experts=min(cfg.moe_experts, 4),
+        moe_experts=moe_experts,
+        moe_topk=min(cfg.moe_topk, max(moe_experts, 1)),
+        moe_held=0,
+        moe_held_first=0,
+        moe_shared_dff=min(cfg.moe_shared_dff, 256),
         window=min(cfg.window, 64) if cfg.window else None,
         enc_layers=min(cfg.enc_layers, 2),
         enc_seq=min(cfg.enc_seq, 32),

@@ -75,6 +75,7 @@ def attn_forward(
         q_offset=q_offset,
         segment_q=segment_ids,
         segment_k=segment_ids,
+        scale=cfg.attn_scale,
         p_bf16=cfg.attn_p_bf16,
     )
     out = out.reshape(B, S, cfg.n_heads * cfg.hd) @ p["wo"]
@@ -132,7 +133,8 @@ def attn_decode(
     kc = write_decode_rows(cache["k"], k[:, 0], slot)
     vc = write_decode_rows(cache["v"], v[:, 0], slot)
     kv_len = jnp.minimum(pos + 1, T) if window is not None else pos + 1
-    out = decode_attention(q, kc, vc, kv_len, logit_cap=cfg.attn_softcap)
+    out = decode_attention(q, kc, vc, kv_len, logit_cap=cfg.attn_softcap,
+                           scale=cfg.attn_scale)
     out = out.reshape(B, 1, cfg.n_heads * cfg.hd) @ p["wo"]
     return out, {"k": kc, "v": vc}
 
@@ -156,7 +158,8 @@ def cross_attn_forward(
     nq, nkv, hd = cfg.n_heads, cfg.n_kv, cfg.hd
     q = _split_heads(x @ p["wq"], nq, hd).reshape(B, S, nkv, nq // nkv, hd)
     k, v = enc_kv
-    out = flash_attention(q, k, v, causal=False, logit_cap=cfg.attn_softcap)
+    out = flash_attention(q, k, v, causal=False, logit_cap=cfg.attn_softcap,
+                          scale=cfg.attn_scale)
     return out.reshape(B, S, nq * hd) @ p["wo"]
 
 

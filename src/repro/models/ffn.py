@@ -1,6 +1,11 @@
 """Feed-forward layers: dense GLU variants and top-k MoE.
 
-The MoE dispatch is *sort-based* (argsort tokens by expert, rank within
+Two MoE dispatches.  Serving takes :func:`dropless_moe_ffn`: it routes over
+every expert, computes the share of the experts held here for each token
+routed to them, drops no token, and so gives each token the same output
+whatever shares its batch.  Training takes :func:`moe_ffn`, below.
+
+The training dispatch is *sort-based* (argsort tokens by expert, rank within
 expert, capacity-bounded scatter into (E, C, d) buffers).  This is the
 HGum-framed-List view of expert dispatch (DESIGN.md §5): per-expert token
 groups are variable-length lists packed into fixed-capacity frames with
@@ -17,6 +22,7 @@ capacity also matches how production MoE systems enforce locality.
 from __future__ import annotations
 
 import math
+from dataclasses import replace
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -59,20 +65,78 @@ def dense_ffn(p: Dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def init_moe_ffn(key, cfg: ModelConfig, dtype) -> Dict:
+    """Router over all ``moe_experts``; weights of the experts held here
+    (``moe_held``, all when 0); a shared expert where ``moe_shared_dff``."""
     d = cfg.d_model
     ff = cfg.moe_dff or cfg.d_ff
     E = cfg.moe_experts
+    held = cfg.moe_held or E
     kr, k1, k2, k3 = jax.random.split(key, 4)
     glu = cfg.act in ("swiglu", "geglu")
     p = {
         "router": dense_init(kr, (d, E), dtype=jnp.float32),  # router in fp32
-        "wi": dense_init(k1, (E, d, ff), in_axis=1, dtype=dtype),
-        "wo": dense_init(k2, (E, ff, d), in_axis=1, dtype=dtype,
+        "wi": dense_init(k1, (held, d, ff), in_axis=1, dtype=dtype),
+        "wo": dense_init(k2, (held, ff, d), in_axis=1, dtype=dtype,
                          scale=1.0 / (2 * cfg.n_layers) ** 0.5),
     }
     if glu:
-        p["wg"] = dense_init(k3, (E, d, ff), in_axis=1, dtype=dtype)
+        p["wg"] = dense_init(k3, (held, d, ff), in_axis=1, dtype=dtype)
+    if cfg.moe_shared_dff:
+        p["shared"] = init_dense_ffn(jax.random.fold_in(key, 4),
+                                     replace(cfg, d_ff=cfg.moe_shared_dff), dtype)
     return p
+
+
+def routed_experts(p: Dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The held experts' share of the routed output, dropping no token.
+
+    The router scores all ``moe_experts``; each token keeps its top
+    ``moe_topk`` logits and softmaxes over them.  The (token, choice) pairs
+    that name a held expert are sorted by expert into one buffer of
+    ``T * min(topk, held)`` rows, the most that can hit the held experts
+    without dropping any, and each expert's rows go through its weights by
+    ``jax.lax.ragged_dot``; pairs of absent experts sort past the groups and
+    are not computed.  Each pair's output comes back to its (token, choice)
+    place and the gates sum them in a fixed order, so a token's output
+    depends only on the token.
+    """
+    B, S, d = x.shape
+    T = B * S
+    E, k = cfg.moe_experts, cfg.moe_topk
+    held = cfg.moe_held or E
+    act = act_fn(cfg.act)
+    xf = x.reshape(T, d)
+
+    logits = xf.astype(jnp.float32) @ p["router"]  # (T, E)
+    top_l, top_i = jax.lax.top_k(logits, k)
+    gates = jax.nn.softmax(top_l, axis=-1)  # over the chosen logits
+    local = top_i - cfg.moe_held_first
+    mine = ((local >= 0) & (local < held)).reshape(-1)  # (T*k,)
+    key = jnp.where(mine, local.reshape(-1), held)
+    order = jnp.argsort(key, stable=True)
+    M = T * min(k, held)
+    sizes = jnp.bincount(key, length=held + 1)[:held].astype(jnp.int32)
+    rows = xf[order[:M] // k]  # (M, d), grouped by expert
+    h = jax.lax.ragged_dot(rows, p["wi"], sizes)
+    if "wg" in p:
+        h = act(jax.lax.ragged_dot(rows, p["wg"], sizes)) * h
+    else:
+        h = act(h)
+    out = jax.lax.ragged_dot(h.astype(x.dtype), p["wo"], sizes)  # (M, d)
+
+    where = jnp.argsort(order)  # each pair's row in the sorted buffer
+    pair = jnp.where(mine[:, None], out[jnp.minimum(where, M - 1)], 0).reshape(T, k, d)
+    y = jnp.sum(gates[:, :, None] * pair.astype(jnp.float32), axis=1)
+    return y.astype(x.dtype).reshape(B, S, d)
+
+
+def dropless_moe_ffn(p: Dict, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """The serving MoE layer: the held experts' routed share, with the
+    shared expert beside it."""
+    y = routed_experts(p, x, cfg)
+    if "shared" in p:
+        y = y + dense_ffn(p["shared"], x, cfg)
+    return y
 
 
 def moe_capacity(cfg: ModelConfig, n_tokens: int) -> int:
@@ -127,7 +191,10 @@ def moe_ffn(
     p: Dict, x: jnp.ndarray, cfg: ModelConfig, capacity: Optional[int] = None,
     token_group: int = TOKEN_GROUP,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """Top-k capacity-bounded MoE over token groups (see module docstring)."""
+    """Top-k capacity-bounded MoE over token groups (see module docstring),
+    for training: every expert held, tokens past a capacity dropped."""
+    if cfg.moe_held and cfg.moe_held != cfg.moe_experts:
+        raise ValueError("the capacity-bound dispatch holds every expert")
     B, S, d = x.shape
     T = B * S
     act = act_fn(cfg.act)

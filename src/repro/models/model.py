@@ -2,7 +2,8 @@
 
 One parameter pytree + three entry points:
 
-* ``forward(params, cfg, batch)``            — full-sequence logits (train).
+* ``forward(params, cfg, batch)``            — full-sequence logits (``train=True``
+  for the loss, with the training MoE dispatch).
 * ``prefill(params, cfg, batch, cache_len)`` — forward + primed KV/SSM cache.
 * ``decode_step(params, cfg, cache, batch)`` — one token, updated cache.
 
@@ -106,8 +107,10 @@ def layer_forward(
     positions: Optional[jnp.ndarray] = None,  # (B,S) full-seq positions
     segment_ids: Optional[jnp.ndarray] = None,
     q_offset: int | jnp.ndarray = 0,
+    train: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[Dict], Dict]:
-    """Returns (x, new_cache, aux)."""
+    """Returns (x, new_cache, aux).  An MoE layer takes the capacity-bound
+    dispatch when ``train`` and the dropless one otherwise."""
     aux: Dict = {}
     h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     new_cache: Dict = {}
@@ -135,18 +138,29 @@ def layer_forward(
         raise ValueError(seq_kind)
     if cfg.sandwich_norm:
         out = apply_norm(cfg.norm, p["ln1_post"], out, cfg.norm_eps)
-    x = x + out
+    x = x + _residual(out, cfg)
 
     if ffn_kind != "none":
         h = apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
         if ffn_kind == "dense":
             out = ffn_mod.dense_ffn(p["ffn"], h, cfg)
-        else:
+        elif train:
             out, aux = ffn_mod.moe_ffn(p["moe"], h, cfg)
+            if "shared" in p["moe"]:
+                out = out + ffn_mod.dense_ffn(p["moe"]["shared"], h, cfg)
+        else:
+            out = ffn_mod.dropless_moe_ffn(p["moe"], h, cfg)
         if cfg.sandwich_norm:
             out = apply_norm(cfg.norm, p["ln2_post"], out, cfg.norm_eps)
-        x = x + out
+        x = x + _residual(out, cfg)
     return x, new_cache, aux
+
+
+def _residual(out: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """A residual branch scaled by ``residual_mult`` (granite), where set."""
+    if cfg.residual_mult != 1.0:
+        out = out * jnp.asarray(cfg.residual_mult, out.dtype)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -204,6 +218,8 @@ def _embed_tokens(params, cfg: ModelConfig, tokens: jnp.ndarray) -> jnp.ndarray:
     x = params["embed"][tokens]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+    if cfg.embed_mult != 1.0:
+        x = x * jnp.asarray(cfg.embed_mult, x.dtype)
     return x
 
 
@@ -226,7 +242,10 @@ def _unembed(params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     if cfg.padded_vocab != cfg.vocab:  # mask the pad rows (see padded_vocab)
         pad = jnp.arange(cfg.padded_vocab, dtype=jnp.int32) >= cfg.vocab
         logits = jnp.where(pad, jnp.asarray(-1e30, logits.dtype), logits)
-    return softcap(logits.astype(jnp.float32), cfg.final_softcap)
+    logits = logits.astype(jnp.float32)
+    if cfg.logits_div != 1.0:
+        logits = logits / cfg.logits_div
+    return softcap(logits, cfg.final_softcap)
 
 
 def _sinusoidal(S: int, d: int, offset=0) -> jnp.ndarray:
@@ -269,8 +288,10 @@ def forward(
     want_cache: bool = False,
     cache_len: Optional[int] = None,
     last_only: bool = False,
+    train: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[Dict], Dict]:
     """Returns (logits, cache | None, aux).  ``batch["tokens"]``: (B,S).
+    ``train`` selects the training MoE dispatch (``layer_forward``).
 
     ``last_only`` computes logits for the final position only — prefill
     serving needs just the next token, and (B, S, V) logits at 32k
@@ -305,7 +326,7 @@ def forward(
         s, f = plan[i]
         x, kv, aux = layer_forward(
             lp, x, cfg, i, s, f,
-            mode="full", positions=positions, segment_ids=segment_ids,
+            mode="full", positions=positions, segment_ids=segment_ids, train=train,
         )
         if cfg.family == "encdec":
             cp = params["cross"][i]
@@ -314,7 +335,7 @@ def forward(
         return act_constrain(x, "residual"), kv, aux
 
     if cfg.scan_layers and not want_cache and cfg.family == "lm":
-        x, aux_acc = _forward_scanned(params, cfg, x, positions, segment_ids)
+        x, aux_acc = _forward_scanned(params, cfg, x, positions, segment_ids, train)
     else:
         for i, lp in enumerate(params["layers"]):
             fn = run_layer
@@ -343,7 +364,7 @@ def forward(
     return logits, cache, aux_acc
 
 
-def _forward_scanned(params, cfg, x, positions, segment_ids):
+def _forward_scanned(params, cfg, x, positions, segment_ids, train):
     """Scan over stacked layer periods (see module docstring)."""
     p = plan_period(cfg)
     n_periods = cfg.n_layers // p
@@ -355,7 +376,7 @@ def _forward_scanned(params, cfg, x, positions, segment_ids):
             s, f = plan[j]
             x, _, aux = layer_forward(
                 period_params[f"pos{j}"], x, cfg, j, s, f,
-                mode="full", positions=positions, segment_ids=segment_ids,
+                mode="full", positions=positions, segment_ids=segment_ids, train=train,
             )
             x = act_constrain(x, "residual")
         return x, aux.get("moe_balance_loss", jnp.zeros(()))
@@ -509,7 +530,7 @@ def prefill(
 def loss_fn(
     params: PyTree, cfg: ModelConfig, batch: Dict
 ) -> Tuple[jnp.ndarray, Dict]:
-    logits, _, aux = forward(params, cfg, batch)
+    logits, _, aux = forward(params, cfg, batch, train=True)
     loss, metrics = cross_entropy(
         logits, batch["labels"], batch.get("loss_mask"), z_loss=1e-4
     )

@@ -43,14 +43,21 @@ def mamba_dims(cfg: ModelConfig) -> Tuple[int, int, int, int]:
     return d_in, nh, cfg.ssm_head, cfg.ssm_state
 
 
+def mamba_conv_dim(cfg: ModelConfig) -> int:
+    """Channels of the depthwise conv: x, B and C (one group) together."""
+    d_in, _, _, N = mamba_dims(cfg)
+    return d_in + 2 * N
+
+
 def init_mamba(key, cfg: ModelConfig, dtype) -> Dict:
     d = cfg.d_model
     d_in, nh, P, N = mamba_dims(cfg)
+    conv_dim = mamba_conv_dim(cfg)
     k1, k2, k3, k4 = jax.random.split(key, 4)
     return {
         "in_proj": dense_init(k1, (d, 2 * d_in + 2 * N + nh), dtype=dtype),
-        "conv_w": (jax.random.normal(k2, (cfg.ssm_conv, d_in)) * 0.1).astype(dtype),
-        "conv_b": jnp.zeros((d_in,), dtype),
+        "conv_w": (jax.random.normal(k2, (cfg.ssm_conv, conv_dim)) * 0.1).astype(dtype),
+        "conv_b": jnp.zeros((conv_dim,), dtype),
         "A_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
         "D": jnp.ones((nh,), jnp.float32),
         "dt_bias": jnp.zeros((nh,), jnp.float32),
@@ -60,11 +67,16 @@ def init_mamba(key, cfg: ModelConfig, dtype) -> Dict:
     }
 
 
-def _mamba_split(p, x, cfg):
+def _mamba_in(p, x, cfg, conv_state):
+    """Mamba-2's input side: in_proj to (z, xBC, dt), the causal conv and
+    SiLU over x, B and C together, then the split.  Returns (z, xs, B, C, dt,
+    new conv state)."""
     d_in, nh, P, N = mamba_dims(cfg)
     zxbcdt = x @ p["in_proj"]
-    z, xs, Bm, Cm, dt = jnp.split(zxbcdt, [d_in, 2 * d_in, 2 * d_in + N, 2 * d_in + 2 * N], -1)
-    return z, xs, Bm, Cm, dt
+    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * N], -1)
+    xbc, conv_state = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
+    xs, Bm, Cm = jnp.split(xbc, [d_in, d_in + N], -1)
+    return z, xs, Bm, Cm, dt, conv_state
 
 
 def _causal_conv(xs, w, b, state=None):
@@ -80,15 +92,20 @@ def _causal_conv(xs, w, b, state=None):
     return jax.nn.silu(out + b), new_state
 
 
+def _gated_norm(p, y, z, cfg):
+    """Mamba-2's output norm: RMSNorm of y * silu(z) in float32 (one group),
+    at the configuration's eps."""
+    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rmsnorm(g, p["norm"], cfg.norm_eps).astype(z.dtype)
+
+
 def mamba_forward(
     p: Dict, x: jnp.ndarray, cfg: ModelConfig, state: Optional[Dict] = None
 ) -> Tuple[jnp.ndarray, Dict]:
     """Full-sequence Mamba (chunked SSD).  x (B,S,d) -> (out, new_state)."""
     B, S, d = x.shape
     d_in, nh, P, N = mamba_dims(cfg)
-    z, xs, Bm, Cm, dt = _mamba_split(p, x, cfg)
-    conv_state = state["conv"] if state else None
-    xs, conv_state = _causal_conv(xs, p["conv_w"], p["conv_b"], conv_state)
+    z, xs, Bm, Cm, dt, conv_state = _mamba_in(p, x, cfg, state["conv"] if state else None)
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,S,nh)
     A = -jnp.exp(p["A_log"])  # (nh,)
@@ -145,8 +162,7 @@ def mamba_forward(
     )
     y = ys.swapaxes(0, 1).reshape(B, nC * L, nh, P)[:, :S]
     y = y + xh.reshape(B, nC * L, nh, P)[:, :S] * p["D"][None, None, :, None]
-    y = y.reshape(B, S, d_in).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"])
+    y = _gated_norm(p, y.reshape(B, S, d_in), z, cfg)
     out = y @ p["out_proj"]
     return out, {"conv": conv_state, "ssm": S_fin}
 
@@ -157,8 +173,7 @@ def mamba_decode(
     """Single-step Mamba.  x (B,1,d)."""
     B = x.shape[0]
     d_in, nh, P, N = mamba_dims(cfg)
-    z, xs, Bm, Cm, dt = _mamba_split(p, x, cfg)
-    xs, conv_state = _causal_conv(xs, p["conv_w"], p["conv_b"], state["conv"])
+    z, xs, Bm, Cm, dt, conv_state = _mamba_in(p, x, cfg, state["conv"])
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])[:, 0]  # (B,nh)
     A = -jnp.exp(p["A_log"])
     da = jnp.exp(dt * A[None, :])  # (B,nh)
@@ -169,15 +184,14 @@ def mamba_decode(
         "bh,bhp,bn->bhpn", dt, xh, Bv
     )
     y = jnp.einsum("bn,bhpn->bhp", Cv, S_new) + xh * p["D"][None, :, None]
-    y = y.reshape(B, 1, d_in).astype(x.dtype)
-    y = rmsnorm(y * jax.nn.silu(z), p["norm"])
+    y = _gated_norm(p, y.reshape(B, 1, d_in), z, cfg)
     return y @ p["out_proj"], {"conv": conv_state, "ssm": S_new}
 
 
 def mamba_init_state(cfg: ModelConfig, batch: int, dtype) -> Dict:
     d_in, nh, P, N = mamba_dims(cfg)
     return {
-        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, d_in), dtype),
+        "conv": jnp.zeros((batch, cfg.ssm_conv - 1, mamba_conv_dim(cfg)), dtype),
         "ssm": jnp.zeros((batch, nh, P, N), jnp.float32),
     }
 
