@@ -319,6 +319,16 @@ class Router:
     def _coord_int(self, rank: int, ai: int) -> int:
         return (rank // self._stride(ai)) % self.sizes[ai]
 
+    def rank_device(self, rank: int):
+        """The device that holds ``rank``'s block of every routed array:
+        the mesh's devices in ``axis_names`` order, flattened row-major
+        (the first, where the mesh has axes the fabric does not route)."""
+        names = list(self.mesh.axis_names)
+        order = [names.index(a) for a in self.axis_names]
+        order += [i for i in range(len(names)) if i not in order]
+        devices = np.transpose(self.mesh.devices, order)
+        return devices.reshape(self.n_ranks, -1)[rank, 0]
+
     def hops(self, src: int, dst: int) -> int:
         """Total +1-ring (dimension-order) hops from src to dst.
 

@@ -62,7 +62,7 @@ from ..core import (
 from ..data.schemas import request_schema, response_schema
 from ..models import init_params
 from ..obs.trace import span
-from ..runtime.scheduler import ContinuousBatcher, SchedulerConfig
+from ..runtime.scheduler import ContinuousBatcher, SchedulerConfig, params_on
 from .compile_cache import use_compile_cache
 from .steps import make_prefill_step, make_serve_step
 
@@ -574,9 +574,11 @@ def serve_requests_streaming(
 ) -> List[bytes]:
     """Answer N request wires with token-level streamed responses.
 
-    Same placement and compute as ``serve_requests_sharded`` — rank-0
-    ingress, nearest-free-shard placement, one ContinuousBatcher per shard
-    — but the response path streams: every decode tick, each shard writes
+    Same placement as ``serve_requests_sharded`` — rank-0 ingress,
+    nearest-free-shard placement, one ContinuousBatcher per shard, here on
+    the device of the shard's fabric rank with its own copy of the
+    weights, so the shards' steps run at once — but the response path
+    streams: every decode tick, each shard writes
     the step's tokens into per-sequence ``StreamWriter``s and one
     ``ChunkLane`` burst per (shard, tenant) rides the fabric back, so the
     ingress sees each token one fabric tick after it decodes instead of
@@ -687,6 +689,12 @@ def serve_requests_streaming(
     if analyze:
         _analyze_serve(fabric, len(wires), "serve_requests_streaming")
     shards = list(range(1, fabric.n_ranks))
+    # each shard serves on the device of its fabric rank; every shard's
+    # weight copy starts before any shard waits on one, and later calls
+    # with the same params reuse the copies
+    shard_device = {s: fabric.router.rank_device(s) for s in shards}
+    for dev in shard_device.values():
+        params_on(params, dev)
     ingress = fabric.mailbox(0)
     reqs = decode_request_batch(wires)  # ingress keeps rids + prompt counts
     if placement is None:
@@ -772,7 +780,8 @@ def serve_requests_streaming(
         batcher = batchers.get(s)
         if batcher is None:
             batcher = ContinuousBatcher(params, cfg, sched, metrics=metrics,
-                                        spans=spans, logprobs=logprobs)
+                                        spans=spans, logprobs=logprobs,
+                                        device=shard_device[s])
             batchers[s] = batcher
             if metrics is not None:
                 # the device holding this shard's slot cache, where its

@@ -18,11 +18,15 @@ request.  This module is the compute half of the batched message plane
 :meth:`step` is split into :meth:`step_begin` / :meth:`step_finish` so a
 driver can overlap fabric ticks with compute (ISSUE 3's streaming plane):
 ``step_begin`` dispatches the admit prefill and the batched decode — JAX
-async dispatch returns before the device finishes — and ``step_finish``
-performs the one host sync, records the tick's tokens, and returns them as
-``(seq_id, position, token)`` emissions for the per-sequence StreamWriters.
-Between the two calls the host is free to reap/dispatch
-``Fabric.exchange_async`` ticks while the decode step runs.
+async dispatch returns before the device finishes, and nothing in it reads
+a result back — and ``step_finish`` performs the one host sync (the
+admitted sequences' first tokens and the decode step's tokens together),
+records the tick's tokens, and returns them as ``(seq_id, position,
+token)`` emissions for the per-sequence StreamWriters.  Between the two
+calls the host is free to reap/dispatch ``Fabric.exchange_async`` ticks
+while the decode step runs, and to dispatch the next batcher's tick: given
+a ``device``, a batcher keeps its weights, slot cache and steps on that
+device, so batchers on different devices compute at once.
 
 Sequences are plain token lists; the wire plane (``launch.serve``) sits on
 either side of this class — batched HGum DES in front, bulk SER behind.
@@ -30,6 +34,7 @@ either side of this class — batched HGum DES in front, bulk SER behind.
 from __future__ import annotations
 
 import functools
+import weakref
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Deque, Dict, Hashable, List, Optional, Tuple
@@ -83,6 +88,42 @@ class _Sequence:
     remaining: int = 0
 
 
+@dataclass
+class _Admission:
+    """One admit prefill whose first tokens ``step_finish`` reads back."""
+
+    seqs: List[_Sequence]
+    tok: jnp.ndarray  # (admit_width, 1) first token of each prefilled row
+    lp: Optional[jnp.ndarray]  # its logprob, with ``logprobs=True``
+
+
+#: (id of the first leaf, device) -> (treedef, weakrefs to the source
+#: leaves, the copy); an entry leaves when its first source leaf is freed
+_PLACED: Dict[Tuple[int, Any], Tuple[Any, List[weakref.ref], PyTree]] = {}
+
+
+def params_on(params: PyTree, device) -> PyTree:
+    """``params`` on ``device``, copied once per (params, device).
+
+    A later call with the same leaves returns the same copy, with no new
+    transfer.  The copy is dropped when ``params``' first leaf is freed.
+    The transfer is asynchronous, so copies to several devices started one
+    after another run at once.
+    """
+    leaves, treedef = jax.tree.flatten(params)
+    if all(getattr(x, "devices", lambda: None)() == {device} for x in leaves):
+        return params
+    key = (id(leaves[0]), device)
+    hit = _PLACED.get(key)
+    if (hit is not None and hit[0] == treedef
+            and all(r() is x for r, x in zip(hit[1], leaves))):
+        return hit[2]
+    placed = jax.device_put(params, device, may_alias=False)
+    _PLACED[key] = (treedef, [weakref.ref(x) for x in leaves], placed)
+    weakref.finalize(leaves[0], _PLACED.pop, key, None)
+    return placed
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_rows(cache: PyTree, cur_tok: jnp.ndarray, new_cache: PyTree,
                   new_tok: jnp.ndarray, slot_ids: jnp.ndarray):
@@ -110,9 +151,14 @@ class ContinuousBatcher:
 
     def __init__(self, params: PyTree, cfg: ModelConfig,
                  sched: SchedulerConfig, metrics=None, spans=None,
-                 logprobs: bool = False):
+                 logprobs: bool = False, device=None):
         from ..launch.steps import cached_serve_steps
 
+        #: where the weights, slot cache and step inputs live, and so where
+        #: prefill, decode and the slot scatters run; None = JAX's default
+        self.device = device
+        if device is not None:
+            params = params_on(params, device)
         self.params = params
         self.cfg = cfg
         self.sched = sched
@@ -141,10 +187,14 @@ class ContinuousBatcher:
             )
             cache_spec = out_spec[-1]
             self.cache = jax.tree.map(
-                lambda s: jnp.zeros((sched.slots,) + s.shape[1:], s.dtype), cache_spec
+                lambda s: jnp.zeros((sched.slots,) + s.shape[1:], s.dtype,
+                                    device=device),
+                cache_spec,
             )
-            self.cur_tok = jnp.zeros((sched.slots, 1), jnp.int32)
-            self.cur_lp = jnp.zeros((sched.slots, 1), jnp.float32)
+            self.cur_tok = jnp.zeros((sched.slots, 1), jnp.int32, device=device)
+            self.cur_lp = jnp.zeros((sched.slots, 1), jnp.float32, device=device)
+        (dev,) = self.cur_tok.devices()
+        self._device_id = dev.id
         #: (seq_id, position) -> logprob of every emission of the last tick
         #: (only filled when ``logprobs=True``); the step_finish triple API
         #: is unchanged so logprob-free callers never pay for it
@@ -152,7 +202,7 @@ class ContinuousBatcher:
         # static non-token model inputs (vision/audio placeholders) are
         # allocated once, not per admit tick
         self._extra_inputs = {
-            k: jnp.zeros(s.shape, s.dtype)
+            k: jnp.zeros(s.shape, s.dtype, device=device)
             for k, s in self._batch_specs(sched.admit_width).items()
             if k != "tokens"
         }
@@ -160,7 +210,8 @@ class ContinuousBatcher:
         self.pending: Deque[_Sequence] = deque()
         self.done: Dict[Hashable, List[int]] = {}
         self.steps_run = 0
-        self._tick_emit: List[Tuple[Hashable, int, int]] = []
+        #: admits dispatched since the last step_finish, first tokens unread
+        self._admissions: List[_Admission] = []
         self._stepped = False
 
     def _batch_specs(self, A: int) -> Dict[str, jax.ShapeDtypeStruct]:
@@ -200,7 +251,7 @@ class ContinuousBatcher:
             for j, seq in enumerate(seqs):
                 toks[j, : min(len(seq.tokens), S)] = seq.tokens[:S]
             batch = dict(self._extra_inputs)
-            batch["tokens"] = jnp.asarray(toks)
+            batch["tokens"] = jnp.asarray(toks, device=self.device)
             if self.logprobs:
                 next_tok, next_lp, new_cache = self.prefill_step(self.params, batch)
             else:
@@ -209,53 +260,52 @@ class ContinuousBatcher:
             # unused admit rows -> OOB slot id, dropped by the scatter
             slot_ids = np.full(A, self.sched.slots, np.int32)
             slot_ids[:take] = free[:take]
+            slot_ids = jnp.asarray(slot_ids, device=self.device)
             self.cache, self.cur_tok = _scatter_rows(
-                self.cache, self.cur_tok, new_cache, next_tok, jnp.asarray(slot_ids)
+                self.cache, self.cur_tok, new_cache, next_tok, slot_ids
             )
             if next_lp is not None:
-                self.cur_lp = _scatter_vec(
-                    self.cur_lp, next_lp, jnp.asarray(slot_ids)
-                )
-            with span("batcher.sync"):
-                first = np.asarray(next_tok)[:take, 0]
-                if next_lp is not None:
-                    first_lp = np.asarray(next_lp)[:take, 0]
+                self.cur_lp = _scatter_vec(self.cur_lp, next_lp, slot_ids)
+            # the first tokens are read in step_finish, with the tick's one
+            # sync; a sequence that wants no more than its first token
+            # takes no slot
+            self._admissions.append(_Admission(seqs, next_tok, next_lp))
             for j, seq in enumerate(seqs):
-                seq.out.append(int(first[j]))
                 seq.remaining = self.sched.max_new - 1
-                self.active[free[j]] = seq
-                self._tick_emit.append((seq.seq_id, 0, int(first[j])))
-                if next_lp is not None:
-                    self.tick_logprobs[(seq.seq_id, 0)] = float(first_lp[j])
+                if seq.remaining > 0:
+                    self.active[free[j]] = seq
                 if self.spans is not None and seq.seq_id in self.span_of:
                     self.spans.event(self.span_of[seq.seq_id], "batcher.admit",
                                      slot=free[j])
             if self.metrics is not None:
                 self.metrics.counter("batcher.admitted").add(take)
-            self._evict()
+
+    def _retire(self, seqs: List[_Sequence]) -> None:
+        for seq in seqs:
+            self.done[seq.seq_id] = seq.out
+            if self.spans is not None and seq.seq_id in self.span_of:
+                self.spans.event(self.span_of[seq.seq_id],
+                                 "batcher.evict", n_out=len(seq.out))
+        if self.metrics is not None and seqs:
+            self.metrics.counter("batcher.evicted").add(len(seqs))
 
     def _evict(self) -> None:
-        evicted = 0
+        finished = []
         for i, seq in enumerate(self.active):
             if seq is not None and seq.remaining <= 0:
-                self.done[seq.seq_id] = seq.out
                 self.active[i] = None
-                evicted += 1
-                if self.spans is not None and seq.seq_id in self.span_of:
-                    self.spans.event(self.span_of[seq.seq_id],
-                                     "batcher.evict", n_out=len(seq.out))
-        if self.metrics is not None and evicted:
-            self.metrics.counter("batcher.evicted").add(evicted)
+                finished.append(seq)
+        self._retire(finished)
 
     def step_begin(self) -> bool:
         """Dispatch one scheduler tick: admit into free slots, then launch
         one batched decode step for every live slot.
 
         Returns immediately after dispatch (JAX async) — the host can run
-        fabric work while the decode executes.  Returns True when a decode
-        step was dispatched.  Must be paired with :meth:`step_finish`.
+        fabric work, or dispatch another batcher's tick, while the device
+        computes.  Returns True when a decode step was dispatched.  Must be
+        paired with :meth:`step_finish`.
         """
-        self._tick_emit = []
         self.tick_logprobs = {}
         self._admit()
         if self.metrics is not None:
@@ -264,7 +314,8 @@ class ContinuousBatcher:
         if self.n_active == 0:
             self._stepped = False
             return False
-        with span("batcher.dispatch", live=self.n_active):
+        with span("batcher.dispatch", live=self.n_active,
+                  device=self._device_id):
             if self.logprobs:
                 self.cur_tok, self.cur_lp, self.cache = self.decode_step(
                     self.params, self.cache, self.cur_tok
@@ -282,27 +333,42 @@ class ContinuousBatcher:
     def step_finish(self) -> List[Tuple[Hashable, int, int]]:
         """Sync the dispatched tick and return its emissions.
 
-        One host sync reads the decode step's tokens; the return value is
-        every token the tick produced — admit-time first tokens included —
-        as ``(seq_id, position, token)`` triples in emission order.
+        One host sync reads the admitted sequences' first tokens and the
+        decode step's tokens; the return value is every token the tick
+        produced — admit-time first tokens first, in admission order, then
+        the decode step's in slot order — as ``(seq_id, position, token)``
+        triples.
         """
-        emitted, self._tick_emit = self._tick_emit, []
-        if not self._stepped:
+        admissions, self._admissions = self._admissions, []
+        stepped, self._stepped = self._stepped, False
+        emitted: List[Tuple[Hashable, int, int]] = []
+        if not admissions and not stepped:
             return emitted
-        self._stepped = False
         with span("batcher.finish"):
             with span("batcher.sync"):  # one host sync per tick
-                toks = np.asarray(self.cur_tok)[:, 0]
-                lps = np.asarray(self.cur_lp)[:, 0] if self.logprobs else None
-            for i, seq in enumerate(self.active):
-                if seq is not None:
-                    seq.out.append(int(toks[i]))
-                    seq.remaining -= 1
-                    pos = len(seq.out) - 1
-                    emitted.append((seq.seq_id, pos, int(toks[i])))
-                    if lps is not None:
-                        self.tick_logprobs[(seq.seq_id, pos)] = float(lps[i])
-            self._evict()
+                firsts, last = jax.device_get((
+                    [(a.tok, a.lp) for a in admissions],
+                    (self.cur_tok, self.cur_lp if self.logprobs else None)
+                    if stepped else None,
+                ))
+            for a, (tok, lp) in zip(admissions, firsts):
+                for j, seq in enumerate(a.seqs):
+                    seq.out.append(int(tok[j, 0]))
+                    emitted.append((seq.seq_id, 0, int(tok[j, 0])))
+                    if lp is not None:
+                        self.tick_logprobs[(seq.seq_id, 0)] = float(lp[j, 0])
+                self._retire([seq for seq in a.seqs if seq.remaining <= 0])
+            if last is not None:
+                toks, lps = last
+                for i, seq in enumerate(self.active):
+                    if seq is not None:
+                        seq.out.append(int(toks[i, 0]))
+                        seq.remaining -= 1
+                        pos = len(seq.out) - 1
+                        emitted.append((seq.seq_id, pos, int(toks[i, 0])))
+                        if lps is not None:
+                            self.tick_logprobs[(seq.seq_id, pos)] = float(lps[i, 0])
+                self._evict()
         return emitted
 
     def step(self) -> None:

@@ -97,10 +97,10 @@ def test_one_chip_spans_in_profiler_trace(serve_setup, batchers, tmp_path):
     for name in ("des.plan", "des.gather", "des.split"):
         assert _inside(_spans(trace, name), _spans(trace, "serve.des")), name
     # 5 sequences through 2-wide admits: three prefills, each read back
+    # with its tick's decode step, in that tick's one sync
     assert len(_spans(trace, "batcher.admit")) == 3
-    assert _inside(_spans(trace, "batcher.sync"),
-                   _spans(trace, "batcher.finish") + _spans(trace, "batcher.admit"))
-    assert len(_spans(trace, "batcher.sync")) == b.steps_run + 3
+    assert _inside(_spans(trace, "batcher.sync"), _spans(trace, "batcher.finish"))
+    assert len(_spans(trace, "batcher.sync")) == b.steps_run
 
 
 def test_streaming_spans_in_profiler_trace(serve_setup, batchers, tmp_path):
@@ -120,6 +120,12 @@ def test_streaming_spans_in_profiler_trace(serve_setup, batchers, tmp_path):
         assert _spans(trace, name), name
     ticks = _spans(trace, "serve.tick")
     assert len(_spans(trace, "batcher.dispatch")) == sum(b.steps_run for b in batchers) > 0
+    # each dispatch names the device its shard computes on, one per shard
+    raw = jax.profiler.ProfileData.from_file(trace_reduce.find_xplane(str(tmp_path)))
+    named = {dict(e.stats)["device"] for p in raw.planes for line in p.lines
+             for e in line.events if e.name == "batcher.dispatch"}
+    assert named == {b.device.id for b in batchers}
+    assert len(named) == len(batchers) > 1
     # every compute tick flushes the lanes once and dispatches one exchange
     flushes = [f for f in _spans(trace, "stream.flush") if _inside([f], ticks)]
     assert len(flushes) == len(ticks)
